@@ -25,8 +25,8 @@ import time
 import traceback
 
 MODULES = ["table1", "table2", "fig2_3", "fig4", "fig5_6", "fig7", "fig8_9",
-           "kernels_bench", "prox_bench", "gram_autotune", "roofline_bench",
-           "guard_bench", "serve_bench", "pipeline_bench"]
+           "kernels_bench", "prox_bench", "gram_autotune", "guard_bench",
+           "serve_bench", "pipeline_bench"]
 SMOKE_MODULES = ["kernels_bench", "gram_autotune", "guard_bench",
                  "serve_bench", "pipeline_bench"]
 SMOKE_OUT = os.path.join(os.path.dirname(__file__), os.pardir,

@@ -60,6 +60,7 @@ from repro.kernels.gram import (ColMajorOperand, PacketOperand, PacketPlan,
                                 panel_apply, panel_matvec)
 from repro.kernels.gram.ops import _check_positive_int, _pad_axis
 
+from .hlo_analysis import ENGINE_PHASES, PHASE_PREFIX
 from .sampling import overlap_matrix, sample_blocks
 from .subproblem import block_forward_substitution, choose_jitter
 
@@ -646,6 +647,28 @@ def register_formulation(form: Formulation) -> Formulation:
 
 
 # --------------------------------------------------------------------------
+# Engine phases
+# --------------------------------------------------------------------------
+
+def _phase(name: str):
+    """The named scope of one phase of the s-step loop.  Every op traced
+    under it carries ``engine.<name>`` in its HLO ``op_name``, so a device
+    profile of the compiled solve can be split by phase
+    (:func:`repro.core.hlo_analysis.engine_phase_map`); the scope is metadata
+    and changes nothing in the compiled program.  ``loop`` is the outer
+    scan's own work: slicing each step's index block, its step counter and
+    stacking the per-step history."""
+    assert name in ENGINE_PHASES, name
+    return jax.named_scope(PHASE_PREFIX + name)
+
+
+def _overlap(flat, dtype):
+    """The duplicate-index overlap matrix of an outer step (assembly)."""
+    with _phase("assemble"):
+        return overlap_matrix(flat).astype(dtype)     # shared-seed trick
+
+
+# --------------------------------------------------------------------------
 # The communication point
 # --------------------------------------------------------------------------
 
@@ -659,15 +682,16 @@ def psum_variadic(leaves, axis):
     the all-reduce combiner would otherwise emit one op per array (the
     ROADMAP's 2-all-reduces-per-iteration artifact), which breaks the
     latency accounting the collective-count tests pin down."""
-    shapes = [x.shape for x in leaves]
-    flat = jnp.concatenate([x.ravel() for x in leaves])
-    red = jax.lax.psum(flat, axis)
-    out, off = [], 0
-    for sh in shapes:
-        size = math.prod(sh)
-        out.append(red[off:off + size].reshape(sh))
-        off += size
-    return out
+    with _phase("reduce"):
+        shapes = [x.shape for x in leaves]
+        flat = jnp.concatenate([x.ravel() for x in leaves])
+        red = jax.lax.psum(flat, axis)
+        out, off = [], 0
+        for sh in shapes:
+            size = math.prod(sh)
+            out.append(red[off:off + size].reshape(sh))
+            off += size
+        return out
 
 
 def ring_hops(axis_sizes, law: tuple = (2, -2)) -> int:
@@ -734,25 +758,26 @@ def ring_reduce_variadic(leaves, axis, axis_sizes, overlap_fn=None):
     stay replicated), but the association differs from psum's tree -- equal
     to the psum wire to f64 ~1e-12, not bit-for-bit.
     """
-    shapes = [x.shape for x in leaves]
-    flat = jnp.concatenate([x.ravel() for x in leaves])
-    size = flat.shape[0]
-    extra = None
-    for name, P in zip(_axes(axis), axis_sizes):
-        if P == 1:
-            continue
-        buf, me = _ring_reduce_scatter(flat, name, P)
+    with _phase("reduce"):
+        shapes = [x.shape for x in leaves]
+        flat = jnp.concatenate([x.ravel() for x in leaves])
+        size = flat.shape[0]
+        extra = None
+        for name, P in zip(_axes(axis), axis_sizes):
+            if P == 1:
+                continue
+            buf, me = _ring_reduce_scatter(flat, name, P)
+            if extra is None and overlap_fn is not None:
+                extra = overlap_fn()
+            flat = _ring_all_gather(buf, me, name, P)[:size]
         if extra is None and overlap_fn is not None:
-            extra = overlap_fn()
-        flat = _ring_all_gather(buf, me, name, P)[:size]
-    if extra is None and overlap_fn is not None:
-        extra = overlap_fn()        # degenerate all-size-1 mesh: no hops
-    out, off = [], 0
-    for sh in shapes:
-        sz = math.prod(sh)
-        out.append(flat[off:off + sz].reshape(sh))
-        off += sz
-    return out, extra
+            extra = overlap_fn()    # degenerate all-size-1 mesh: no hops
+        out, off = [], 0
+        for sh in shapes:
+            sz = math.prod(sh)
+            out.append(flat[off:off + sz].reshape(sh))
+            off += sz
+        return out, extra
 
 
 def _packet_reduce(G_local, r_local, axis, fuse, health=None):
@@ -774,10 +799,11 @@ def _packet_reduce(G_local, r_local, axis, fuse, health=None):
         G, r, h = psum_variadic([G_local, r_local, health], axis)
         return G, r, h
     if fuse:
-        sb = G_local.shape[0]
-        packet = jax.lax.psum(
-            jnp.concatenate([G_local, r_local[:, None]], axis=1), axis)
-        return packet[:, :sb], packet[:, sb], None
+        with _phase("reduce"):
+            sb = G_local.shape[0]
+            packet = jax.lax.psum(
+                jnp.concatenate([G_local, r_local[:, None]], axis=1), axis)
+            return packet[:, :sb], packet[:, sb], None
     G, r = psum_variadic([G_local, r_local], axis)
     return G, r, None
 
@@ -955,32 +981,33 @@ def _assemble_subproblem(bound, G0, r, carry, flat, O, sb: int, scale=None):
     driver's straight-line step performs -- the regularized diagonal lands
     an ulp apart.  A traced per-item scale pins the mul inside the loop
     next to the add, restoring the single driver's contraction."""
-    dtype = G0.dtype
-    # reg*O built as a SELECT on a barriered reg, not a multiply: O is a
-    # 0/1 matrix, so the values are identical, but a mul here would compete
-    # with scale*G0 for the fma contraction, and a python-float reg (single
-    # driver) would constant-fold where a traced per-tenant reg (batched
-    # driver) cannot -- either asymmetry leaves the two drivers' compiled
-    # assemblies an ulp apart.  The barrier keeps reg a runtime value (that
-    # much IS within optimization_barrier's power), so every driver carries
-    # the same live select and scale*G0 is the only contractible mul.
-    mask = jnp.eye(sb, dtype=bool) if O is None else O != 0
-    regO = jnp.where(mask,
-                     jax.lax.optimization_barrier(
-                         jnp.asarray(bound.reg, dtype)),
-                     jnp.zeros((), dtype))
-    A = (bound.scale if scale is None else scale) * G0 + regO
-    # The residual scale is applied HERE, not in the kernel epilogue: a
-    # kernel-fused ``scale_r*acc`` sits in the same compiled body as the
-    # formulation rhs and fma-contracts into it (single driver), while a
-    # residual that crossed a loop or module boundary (batched driver, any
-    # psum) arrives rounded -- an ulp apart on warm iterates.  With r raw
-    # from the kernel, both drivers run this same mul-into-rhs seam and
-    # contract identically.  scale_r is a static python float (1/n, or the
-    # dual's exact 1.0, which folds), so no hoisting hazard arises: the mul
-    # partner r is per-tenant/per-step either way.
-    scale_r = bound.scale if bound.scale_r is None else bound.scale_r
-    return A, bound.base(scale_r * r, carry, flat)
+    with _phase("assemble"):
+        dtype = G0.dtype
+        # reg*O built as a SELECT on a barriered reg, not a multiply: O is a
+        # 0/1 matrix, so the values are identical, but a mul here would compete
+        # with scale*G0 for the fma contraction, and a python-float reg (single
+        # driver) would constant-fold where a traced per-tenant reg (batched
+        # driver) cannot -- either asymmetry leaves the two drivers' compiled
+        # assemblies an ulp apart.  The barrier keeps reg a runtime value (that
+        # much IS within optimization_barrier's power), so every driver carries
+        # the same live select and scale*G0 is the only contractible mul.
+        mask = jnp.eye(sb, dtype=bool) if O is None else O != 0
+        regO = jnp.where(mask,
+                         jax.lax.optimization_barrier(
+                             jnp.asarray(bound.reg, dtype)),
+                         jnp.zeros((), dtype))
+        A = (bound.scale if scale is None else scale) * G0 + regO
+        # The residual scale is applied HERE, not in the kernel epilogue: a
+        # kernel-fused ``scale_r*acc`` sits in the same compiled body as the
+        # formulation rhs and fma-contracts into it (single driver), while a
+        # residual that crossed a loop or module boundary (batched driver, any
+        # psum) arrives rounded -- an ulp apart on warm iterates.  With r raw
+        # from the kernel, both drivers run this same mul-into-rhs seam and
+        # contract identically.  scale_r is a static python float (1/n, or the
+        # dual's exact 1.0, which folds), so no hoisting hazard arises: the mul
+        # partner r is per-tenant/per-step either way.
+        scale_r = bound.scale if bound.scale_r is None else bound.scale_r
+        return A, bound.base(scale_r * r, carry, flat)
 
 
 def _outer_step(bound: BoundFormulation, plan: SolverPlan, s_k: int, carry,
@@ -1012,63 +1039,70 @@ def _outer_step(bound: BoundFormulation, plan: SolverPlan, s_k: int, carry,
     dtype = bound.operand.dtype
     flat = idx_k.reshape(sb)
     dist = axis is not None
-    u = bound.packet_vector(carry)
-    # The packet leaves the kernel fully RAW (scale=1, scale_r=1, reg=0):
-    # every scale and the regularizer are applied post-contraction by the
-    # one shared :func:`_assemble_subproblem`, so the single and
-    # tenant-batched drivers run the identical assembly graph (see that
-    # helper for why identical graphs -- not fences -- are what keeps them
-    # bit-for-bit, and why a kernel-fused scale_r in particular would
-    # contract into the rhs here but not in the batched driver).
-    Gl, rl = gram_packet_sampled(bound.operand, flat, u,
-                                 scale=1.0, scale_r=1.0,
-                                 reg=0.0, plan=pp)
-    if plan.fault is not None:
-        Gl, rl = plan.fault.apply_packet(Gl, rl, step=step, axis=axis)
-    health = None
-    if plan.guard:
-        health = _health_local(Gl, rl, carry, u, dtype)
+    with _phase("packet"):
+        u = bound.packet_vector(carry)
+        # The packet leaves the kernel fully RAW (scale=1, scale_r=1,
+        # reg=0): every scale and the regularizer are applied
+        # post-contraction by the one shared :func:`_assemble_subproblem`,
+        # so the single and tenant-batched drivers run the identical
+        # assembly graph (see that helper for why identical graphs -- not
+        # fences -- are what keeps them bit-for-bit, and why a kernel-fused
+        # scale_r in particular would contract into the rhs here but not in
+        # the batched driver).
+        Gl, rl = gram_packet_sampled(bound.operand, flat, u,
+                                     scale=1.0, scale_r=1.0,
+                                     reg=0.0, plan=pp)
         if plan.fault is not None:
-            health = plan.fault.apply_health(health, step=step, axis=axis)
+            Gl, rl = plan.fault.apply_packet(Gl, rl, step=step, axis=axis)
+        health = None
+        if plan.guard:
+            health = _health_local(Gl, rl, carry, u, dtype)
+            if plan.fault is not None:
+                health = plan.fault.apply_health(health, step=step, axis=axis)
     G, r, h = _packet_reduce(Gl, rl, axis, plan.fuse_packet, health)
     if dist or s_k > 1:
-        O = overlap_matrix(flat).astype(dtype)             # shared-seed trick
+        O = _overlap(flat, dtype)
     else:
         O = None        # a single block has no cross-block overlap terms
     A, base = _assemble_subproblem(bound, G, r, carry, flat, O, sb)
-    if plan.guard:
-        dxs, gstate, ginfo = _guarded_sweep(bound, plan, A, base, s_k, b,
-                                            flat, carry, O, h, gstate, step,
-                                            n_shards, dtype)
-    else:
-        dxs = bound.inner_sweep(A, base, s_k, b, flat, carry, O)
-        ginfo = None
+    with _phase("sweep"):
+        if plan.guard:
+            dxs, gstate, ginfo = _guarded_sweep(bound, plan, A, base, s_k, b,
+                                                flat, carry, O, h, gstate,
+                                                step, n_shards, dtype)
+        else:
+            dxs = bound.inner_sweep(A, base, s_k, b, flat, carry, O)
+            ginfo = None
 
     if not collect:
         # Fast path (distributed): apply all s_k blocks in one deferred
         # update -- sum_j Y_j^T dx_j == Y^T dxs.
-        return bound.update(carry, flat, dxs, pp), gstate, None
+        with _phase("update"):
+            return bound.update(carry, flat, dxs, pp), gstate, None
 
     # Metric path: reconstruct the per-inner-iteration trajectory locally.
+    # The inner scan exists for the history, so its own work is metrics.
     def inner(c, j):
         sl = jax.lax.dynamic_slice_in_dim
-        c = bound.update(c, sl(flat, j * b, b), sl(dxs, j * b, b), pp)
+        with _phase("update"):
+            c = bound.update(c, sl(flat, j * b, b), sl(dxs, j * b, b), pp)
         return c, bound.metrics(c)
 
-    carry, hist = jax.lax.scan(inner, carry, jnp.arange(s_k))
-    if plan.track_cond:
-        # Fig. 4i conditions the scaled packet with its ridge diagonal
-        # (scale*G + reg*I) -- the quantity the kernel used to emit when
-        # scale/reg were fused.  The packet now leaves the kernel raw, so
-        # rebuild it here; A is NOT it (A's off-diagonal overlap entries
-        # shift the spectrum at s > 1).
-        Greg = bound.scale * G + bound.reg * jnp.eye(sb, dtype=dtype)
-        hist["gram_cond"] = jnp.full((s_k,), jnp.linalg.cond(Greg))
-    if ginfo is not None:
-        # Guard telemetry broadcast to the inner-iteration grid so it
-        # concatenates with the other history series.
-        for k, v in ginfo.items():
-            hist[k] = jnp.full((s_k,), v)
+    with _phase("metrics"):
+        carry, hist = jax.lax.scan(inner, carry, jnp.arange(s_k))
+        if plan.track_cond:
+            # Fig. 4i conditions the scaled packet with its ridge diagonal
+            # (scale*G + reg*I) -- the quantity the kernel used to emit when
+            # scale/reg were fused.  The packet now leaves the kernel raw,
+            # so rebuild it here; A is NOT it (A's off-diagonal overlap
+            # entries shift the spectrum at s > 1).
+            Greg = bound.scale * G + bound.reg * jnp.eye(sb, dtype=dtype)
+            hist["gram_cond"] = jnp.full((s_k,), jnp.linalg.cond(Greg))
+        if ginfo is not None:
+            # Guard telemetry broadcast to the inner-iteration grid so it
+            # concatenates with the other history series.
+            for k, v in ginfo.items():
+                hist[k] = jnp.full((s_k,), v)
     return carry, gstate, hist
 
 
@@ -1079,10 +1113,11 @@ def _gram_only(operand, flat, pp):
     driver's shared-Gram precedent) -- and, crucially, depends only on the
     index stream, never the solver carry, so the pipelined scan can contract
     step k+1's Gram while step k's reduction is on the wire."""
-    u0 = jnp.zeros((operand.contraction,), operand.dtype)
-    G, _ = gram_packet_sampled(operand, flat, u0, scale=1.0, scale_r=0.0,
-                               reg=0.0, plan=pp)
-    return G
+    with _phase("packet"):
+        u0 = jnp.zeros((operand.contraction,), operand.dtype)
+        G, _ = gram_packet_sampled(operand, flat, u0, scale=1.0, scale_r=0.0,
+                                   reg=0.0, plan=pp)
+        return G
 
 
 def _outer_step_pipelined(bound: BoundFormulation, plan: SolverPlan, s_k: int,
@@ -1106,33 +1141,36 @@ def _outer_step_pipelined(bound: BoundFormulation, plan: SolverPlan, s_k: int,
     pp = plan.packet
     dtype = bound.operand.dtype
     flat = idx_k.reshape(sb)
-    u = bound.packet_vector(carry)
-    # Same contraction cells as the fused packet's r (the batched driver's
-    # panel_matvec precedent); raw like every packet, scales applied by the
-    # shared _assemble_subproblem.
-    rl = panel_matvec(bound.operand, flat, u, scale=1.0, plan=pp)
-    if plan.fault is not None:
-        Gl, rl = plan.fault.apply_packet(Gl, rl, step=step, axis=axis)
-    leaves = [Gl, rl]
-    if plan.guard:
-        health = _health_local(Gl, rl, carry, u, dtype)
+    with _phase("packet"):
+        u = bound.packet_vector(carry)
+        # Same contraction cells as the fused packet's r (the batched
+        # driver's panel_matvec precedent); raw like every packet, scales
+        # applied by the shared _assemble_subproblem.
+        rl = panel_matvec(bound.operand, flat, u, scale=1.0, plan=pp)
         if plan.fault is not None:
-            health = plan.fault.apply_health(health, step=step, axis=axis)
-        leaves.append(health)
+            Gl, rl = plan.fault.apply_packet(Gl, rl, step=step, axis=axis)
+        leaves = [Gl, rl]
+        if plan.guard:
+            health = _health_local(Gl, rl, carry, u, dtype)
+            if plan.fault is not None:
+                health = plan.fault.apply_health(health, step=step, axis=axis)
+            leaves.append(health)
     red, Gl_next = ring_reduce_variadic(
         leaves, axis, axis_sizes,
         overlap_fn=lambda: _gram_only(bound.operand, flat_next, pp))
     G, r = red[0], red[1]
     h = red[2] if plan.guard else None
-    O = overlap_matrix(flat).astype(dtype)
+    O = _overlap(flat, dtype)
     A, base = _assemble_subproblem(bound, G, r, carry, flat, O, sb)
-    if plan.guard:
-        dxs, gstate, _ = _guarded_sweep(bound, plan, A, base, s_k, b, flat,
-                                        carry, O, h, gstate, step, n_shards,
-                                        dtype)
-    else:
-        dxs = bound.inner_sweep(A, base, s_k, b, flat, carry, O)
-    return bound.update(carry, flat, dxs, pp), gstate, Gl_next
+    with _phase("sweep"):
+        if plan.guard:
+            dxs, gstate, _ = _guarded_sweep(bound, plan, A, base, s_k, b,
+                                            flat, carry, O, h, gstate, step,
+                                            n_shards, dtype)
+        else:
+            dxs = bound.inner_sweep(A, base, s_k, b, flat, carry, O)
+    with _phase("update"):
+        return bound.update(carry, flat, dxs, pp), gstate, Gl_next
 
 
 def _drive_pipelined(bound: BoundFormulation, plan: SolverPlan, idx, *, axis,
@@ -1157,12 +1195,14 @@ def _drive_pipelined(bound: BoundFormulation, plan: SolverPlan, idx, *, axis,
     pp = plan.packet
     iters = idx.shape[0]
     outer_full, rem = divmod(iters, s)
-    carry = bound.init_carry(axes=_axes(axis))
+    with _phase("bind"):
+        carry = bound.init_carry(axes=_axes(axis))
     gstate = _guard_init(bound.operand.dtype) if plan.guard else None
     if outer_full:
         blocks = idx[:outer_full * s].reshape(outer_full, s, b)
         flats = blocks.reshape(outer_full, s * b)
-        flats_next = jnp.concatenate([flats[1:], flats[-1:]])
+        with _phase("packet"):
+            flats_next = jnp.concatenate([flats[1:], flats[-1:]])
         Gl0 = _gram_only(bound.operand, flats[0], pp)
 
         def outer(cg, xs):
@@ -1173,9 +1213,10 @@ def _drive_pipelined(bound: BoundFormulation, plan: SolverPlan, idx, *, axis,
                 n_shards=n_shards)
             return (c, g, Gl), None
         steps = jnp.arange(outer_full, dtype=jnp.int32) + step0
-        (carry, gstate, _), _ = jax.lax.scan(
-            outer, (carry, gstate, Gl0), (steps, blocks, flats_next),
-            unroll=plan.unroll)
+        with _phase("loop"):
+            (carry, gstate, _), _ = jax.lax.scan(
+                outer, (carry, gstate, Gl0), (steps, blocks, flats_next),
+                unroll=plan.unroll)
     if rem:
         flat_t = idx[outer_full * s:].reshape(rem * b)
         Gl_t = _gram_only(bound.operand, flat_t, pp)
@@ -1187,10 +1228,11 @@ def _drive_pipelined(bound: BoundFormulation, plan: SolverPlan, idx, *, axis,
                 axis_sizes=axis_sizes, step=step, gstate=cg[1],
                 n_shards=n_shards)
             return (c, g, Gl), None
-        (carry, gstate, _), _ = jax.lax.scan(
-            tail, (carry, gstate, Gl_t),
-            (jnp.asarray([outer_full + step0], jnp.int32),
-             idx[outer_full * s:][None]))
+        with _phase("loop"):
+            (carry, gstate, _), _ = jax.lax.scan(
+                tail, (carry, gstate, Gl_t),
+                (jnp.asarray([outer_full + step0], jnp.int32),
+                 idx[outer_full * s:][None]))
     return carry, {}, gstate
 
 
@@ -1240,7 +1282,8 @@ def _drive(bound: BoundFormulation, plan: SolverPlan, idx, *, axis=None,
     s, b = plan.s, plan.b
     iters = idx.shape[0]
     outer_full, rem = divmod(iters, s)
-    carry = bound.init_carry(axes=None if axis is None else _axes(axis))
+    with _phase("bind"):
+        carry = bound.init_carry(axes=None if axis is None else _axes(axis))
     gstate = _guard_init(bound.operand.dtype) if plan.guard else None
     hists = []
     if outer_full:
@@ -1251,10 +1294,11 @@ def _drive(bound: BoundFormulation, plan: SolverPlan, idx, *, axis=None,
                                      n_shards=n_shards)
             return (c, g), hist
         steps = jnp.arange(outer_full, dtype=jnp.int32) + step0
-        (carry, gstate), hist = jax.lax.scan(
-            outer, (carry, gstate),
-            (steps, idx[:outer_full * s].reshape(outer_full, s, b)),
-            unroll=plan.unroll)
+        with _phase("loop"):
+            (carry, gstate), hist = jax.lax.scan(
+                outer, (carry, gstate),
+                (steps, idx[:outer_full * s].reshape(outer_full, s, b)),
+                unroll=plan.unroll)
         if collect:
             hists.append({k: v.reshape(outer_full * s, *v.shape[2:])
                           for k, v in hist.items()})
@@ -1270,15 +1314,18 @@ def _drive(bound: BoundFormulation, plan: SolverPlan, idx, *, axis=None,
                                      axis=axis, collect=collect, step=step,
                                      gstate=cg[1], n_shards=n_shards)
             return (c, g), hist
-        (carry, gstate), hist = jax.lax.scan(
-            tail, (carry, gstate),
-            (jnp.asarray([outer_full + step0], jnp.int32),
-             idx[outer_full * s:][None]))
+        with _phase("loop"):
+            (carry, gstate), hist = jax.lax.scan(
+                tail, (carry, gstate),
+                (jnp.asarray([outer_full + step0], jnp.int32),
+                 idx[outer_full * s:][None]))
         if collect:
             hists.append({k: v.reshape(rem, *v.shape[2:])
                           for k, v in hist.items()})
     if len(hists) > 1:
-        history = {k: jnp.concatenate([h[k] for h in hists]) for k in hists[0]}
+        with _phase("metrics"):
+            history = {k: jnp.concatenate([h[k] for h in hists])
+                       for k in hists[0]}
     else:
         history = hists[0] if hists else {}
     return carry, history, gstate
@@ -1315,7 +1362,8 @@ def s_step_solve(formulation: Formulation | str, plan: SolverPlan,
         idx = sample_blocks(key, form.sample_dim(d, n), plan.b, iters)
     else:
         _check_idx(idx, iters, plan.b)
-    bound = form.bind(X, y, lam, x0=x0, w_ref=w_ref)
+    with _phase("bind"):
+        bound = form.bind(X, y, lam, x0=x0, w_ref=w_ref)
     # Generic carry unpack: formulations may carry extra scan state beyond
     # (w, alpha) -- the accelerated formulation's velocity rides at [2:].
     carry, history, gstate = _drive(bound, plan, idx, step0=step0)
@@ -1392,12 +1440,16 @@ def s_step_solve_sharded(formulation: Formulation | str, plan: SolverPlan,
         _check_idx(idx, iters, plan.b)
     axis_sizes = tuple(mesh.shape[a] for a in _axes(axis))
     n_shards = math.prod(axis_sizes)
-    X, y = form.pad_shards(X, y, n_shards)
+    with _phase("bind"):
+        X, y = form.pad_shards(X, y, n_shards)
     has_x0 = x0 is not None
 
     def body(Xl, yl, idx_rep, *x0_rep):
         kw = {"x0": x0_rep[0]} if has_x0 else {}
-        bound = form.bind_shard(Xl, yl, lam, d=d, n=n, **kw)
+        with _phase("bind"):
+            # The shard-local operand layout (the row slabs' relayout of
+            # Xl) runs here, inside every call.
+            bound = form.bind_shard(Xl, yl, lam, d=d, n=n, **kw)
         carry, _, gstate = _drive(bound, plan, idx_rep, axis=axis,
                                   collect=False, n_shards=n_shards,
                                   step0=step0, axis_sizes=axis_sizes)
@@ -1413,10 +1465,12 @@ def s_step_solve_sharded(formulation: Formulation | str, plan: SolverPlan,
     # accelerated velocity) ride at [2:] and are dropped by dist_finalize.
     if plan.guard:
         carry, gstate = fn(*args)
-        w, alpha = form.dist_finalize(carry[0], carry[1], d, n)
+        with _phase("bind"):
+            w, alpha = form.dist_finalize(carry[0], carry[1], d, n)
         return w, alpha, _guard_metrics(gstate)
     carry = fn(*args)
-    return form.dist_finalize(carry[0], carry[1], d, n)
+    with _phase("bind"):
+        return form.dist_finalize(carry[0], carry[1], d, n)
 
 
 # --------------------------------------------------------------------------
@@ -1534,7 +1588,8 @@ def _pin_tenant_constants(form, batch: TenantBatch, d: int, n: int,
 def _make_batched_spec(form, batch: TenantBatch, bind_one: Callable, *,
                        per_block: bool, masked: bool) -> _BatchedSpec:
     def bind(y_t, lam_t, coeffs_t, x0_t=None):
-        bound = bind_one(y_t, lam_t, x0_t)
+        with _phase("bind"):
+            bound = bind_one(y_t, lam_t, x0_t)
         return dataclasses.replace(bound, **coeffs_t) if coeffs_t else bound
 
     # Probe with an ARRAY-typed lam so tenant-dependent properties (the
@@ -1546,8 +1601,9 @@ def _make_batched_spec(form, batch: TenantBatch, bind_one: Callable, *,
         # Tenant-independent Gram scale: ship it as a traced per-tenant
         # array anyway, or XLA hoists scale*G0 out of the tenant loop and
         # the assembly loses its fma (see _assemble_subproblem).
-        scales = jnp.full((batch.tenants,), float(probe.scale),
-                          batch.ys.dtype)
+        with _phase("bind"):
+            scales = jnp.full((batch.tenants,), float(probe.scale),
+                              batch.ys.dtype)
     return _BatchedSpec(
         form=form, bind=bind, operand=probe.operand, ys=batch.ys,
         lams=batch.lams, coeffs=batch.coeffs, scales=scales,
@@ -1557,16 +1613,13 @@ def _make_batched_spec(form, batch: TenantBatch, bind_one: Callable, *,
 def _init_batched(spec: _BatchedSpec, batch: TenantBatch, axes):
     # lax.map, not vmap: each tenant's init then lowers exactly like the
     # single solve's (warm starts included), keeping resumes bit-for-bit.
-    if batch.x0s is None:
-        def init(args):
-            y_t, lam_t, coeffs_t = args
-            return spec.bind(y_t, lam_t, coeffs_t).init_carry(axes=axes)
-        return jax.lax.map(init, (batch.ys, batch.lams, batch.coeffs))
-
     def init(args):
-        y_t, lam_t, coeffs_t, x0_t = args
-        return spec.bind(y_t, lam_t, coeffs_t, x0_t).init_carry(axes=axes)
-    return jax.lax.map(init, (batch.ys, batch.lams, batch.coeffs, batch.x0s))
+        return spec.bind(*args).init_carry(axes=axes)
+
+    xs = (batch.ys, batch.lams, batch.coeffs)
+    with _phase("bind"):
+        return jax.lax.map(init, xs if batch.x0s is None
+                           else xs + (batch.x0s,))
 
 
 def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
@@ -1597,10 +1650,6 @@ def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
     # keeps the two drivers' assembly graphs -- and their fma contraction --
     # identical.  The fused residual output is a don't-care (u = 0,
     # scale_r = 0): every real residual is per-tenant.
-    u0 = jnp.zeros((spec.operand.contraction,), dtype)
-    G0, _ = gram_packet_sampled(spec.operand, flat, u0, scale=1.0,
-                                scale_r=0.0, reg=0.0, plan=pp)
-
     def _direction(y_t, lam_t, coeffs_t, carry_t):
         # RAW direction (scale=1), like the single driver's raw packet r:
         # scale_r is applied by the shared _assemble_subproblem next to the
@@ -1608,7 +1657,11 @@ def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
         u = spec.bind(y_t, lam_t, coeffs_t).packet_vector(carry_t)
         return panel_matvec(spec.operand, flat, u, scale=1.0, plan=pp)
 
-    R = jax.vmap(_direction)(spec.ys, spec.lams, spec.coeffs, carries)
+    with _phase("packet"):
+        u0 = jnp.zeros((spec.operand.contraction,), dtype)
+        G0, _ = gram_packet_sampled(spec.operand, flat, u0, scale=1.0,
+                                    scale_r=0.0, reg=0.0, plan=pp)
+        R = jax.vmap(_direction)(spec.ys, spec.lams, spec.coeffs, carries)
 
     if dist:
         # THE sync point, amortized across the tenant axis: one variadic
@@ -1622,7 +1675,7 @@ def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
             G0, R = psum_variadic([G0, R], axis)
 
     if dist or s_k > 1:
-        O = overlap_matrix(flat).astype(dtype)
+        O = _overlap(flat, dtype)
     else:
         O = None            # a single block has no cross-block overlap terms
 
@@ -1636,7 +1689,8 @@ def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
         A, base = _assemble_subproblem(
             bound, G0, r0_t, carry_t, flat, O, sb,
             scale=None if spec.scales is None else sc_t)
-        return bound.inner_sweep(A, base, s_k, b, flat, carry_t, O)
+        with _phase("sweep"):
+            return bound.inner_sweep(A, base, s_k, b, flat, carry_t, O)
 
     # lax.map, NOT vmap: a batched Cholesky/triangular-solve lowers to a
     # different accumulation order than the unbatched one, so vmapping the
@@ -1645,8 +1699,9 @@ def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
     # assembly + sweep is O(s^2 b^2) -- noise next to the shared Gram -- so
     # sequencing it costs nothing while every tenant's subproblem runs
     # through the EXACT op sequence the single solve uses.
-    dxs_all = jax.lax.map(
-        _sweep, (spec.ys, spec.lams, spec.coeffs, R, carries, sc_xs))
+    with _phase("sweep"):
+        dxs_all = jax.lax.map(
+            _sweep, (spec.ys, spec.lams, spec.coeffs, R, carries, sc_xs))
 
     def _apply(args):
         y_t, lam_t, coeffs_t, dxs, carry_t, active_t = args
@@ -1666,15 +1721,18 @@ def _outer_step_batched(spec: _BatchedSpec, plan: SolverPlan, s_k: int, state,
         else:
             carry_t = bound.update(carry_t, flat, dxs, pp)
         if spec.tol is not None:
-            active_t = active_t & (bound.metrics(carry_t)["residual"]
-                                   > spec.tol)
+            with _phase("metrics"):
+                active_t = active_t & (bound.metrics(carry_t)["residual"]
+                                       > spec.tol)
         return carry_t, active_t
 
     # lax.map again: the per-tenant update replays the single solve's exact
     # op sequence (scatter, panel apply, barrier-pinned epilogue) with
     # unbatched lowerings, which a vmap would not guarantee.
-    carries, active = jax.lax.map(
-        _apply, (spec.ys, spec.lams, spec.coeffs, dxs_all, carries, active))
+    with _phase("update"):
+        carries, active = jax.lax.map(
+            _apply, (spec.ys, spec.lams, spec.coeffs, dxs_all, carries,
+                     active))
     return carries, active
 
 
@@ -1690,9 +1748,10 @@ def _drive_batched(spec: _BatchedSpec, plan: SolverPlan, idx, state0, *,
         def outer(st, idx_k):
             return _outer_step_batched(spec, plan, s, st, idx_k, axis=axis,
                                        axis_sizes=axis_sizes), None
-        state, _ = jax.lax.scan(
-            outer, state, idx[:outer_full * s].reshape(outer_full, s, b),
-            unroll=plan.unroll)
+        with _phase("loop"):
+            state, _ = jax.lax.scan(
+                outer, state, idx[:outer_full * s].reshape(outer_full, s, b),
+                unroll=plan.unroll)
     if rem:
         # Length-1 scan for the same reason as _drive's tail: the single
         # driver's tail sees a compiled body with a TRACED index stream, and
@@ -1701,7 +1760,8 @@ def _drive_batched(spec: _BatchedSpec, plan: SolverPlan, idx, state0, *,
         def tail(st, idx_k):
             return _outer_step_batched(spec, plan, rem, st, idx_k, axis=axis,
                                        axis_sizes=axis_sizes), None
-        state, _ = jax.lax.scan(tail, state, idx[outer_full * s:][None])
+        with _phase("loop"):
+            state, _ = jax.lax.scan(tail, state, idx[outer_full * s:][None])
     return state
 
 
@@ -1795,7 +1855,8 @@ def batched_residuals(formulation: Formulation | str, X: jax.Array,
         y_t, lam_t, coeffs_t, carry_t = args
         return spec.bind(y_t, lam_t, coeffs_t).metrics(carry_t)["residual"]
 
-    return jax.lax.map(one, (spec.ys, spec.lams, spec.coeffs, carries))
+    with _phase("metrics"):
+        return jax.lax.map(one, (spec.ys, spec.lams, spec.coeffs, carries))
 
 
 def s_step_solve_batched_sharded(formulation: Formulation | str,
@@ -1831,8 +1892,10 @@ def s_step_solve_batched_sharded(formulation: Formulation | str,
         _check_idx(idx, iters, plan.b)
     axis_sizes = tuple(mesh.shape[a] for a in _axes(axis))
     n_shards = math.prod(axis_sizes)
-    Xp, _ = form.pad_shards(X, batch.ys[0], n_shards)
-    ysp = jax.vmap(lambda y: form.pad_shards(X, y, n_shards)[1])(batch.ys)
+    with _phase("bind"):
+        Xp, _ = form.pad_shards(X, batch.ys[0], n_shards)
+        ysp = jax.vmap(lambda y: form.pad_shards(X, y, n_shards)[1])(
+            batch.ys)
     # Pin host-exact derived constants while the lams are still concrete
     # (inside shard_map they are traced and the pin would be skipped).
     batch = _pin_tenant_constants(form, batch, d, n, X.dtype)
@@ -1868,8 +1931,9 @@ def s_step_solve_batched_sharded(formulation: Formulation | str,
     args = (Xp, ysp, batch.lams, batch.coeffs, idx)
     args += ((batch.x0s,) if has_x0 else ())
     ws, alphas = fn(*args)
-    ws, alphas = jax.vmap(lambda w, a: form.dist_finalize(w, a, d, n))(
-        ws, alphas)
+    with _phase("bind"):
+        ws, alphas = jax.vmap(lambda w, a: form.dist_finalize(w, a, d, n))(
+            ws, alphas)
     return BatchedSolveResult(ws, alphas, jnp.ones((batch.tenants,), bool))
 
 

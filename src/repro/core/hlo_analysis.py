@@ -10,6 +10,8 @@ contract engine (``repro.analysis``) builds its HLO pass on the same parser:
 :func:`parse_named_ops` generalizes the line scan to arbitrary opcodes
 (transpose, gather, fusion) so the PR-5 "no dual pre-transpose" and PR-2
 "panel never materializes" guarantees are checked from one source of truth.
+:func:`engine_phase_map` reads the engine's phase scopes back from each
+instruction's ``op_name``, to label the ops of a device profile.
 
 Conventions, verified against JAX 0.4.37 CPU-backend HLO (fixture snapshots
 of that output live in ``tests/fixtures/hlo/`` so the parser is unit-tested
@@ -173,17 +175,21 @@ _NAMED_OP_RE = re.compile(
     r"(?P<result>%[\w.\-]+)\s*=\s*"
     r"(?P<type>\([^=]*?\)|[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)\s*"
     r"(?P<opcode>[a-z][a-z0-9\-]*)\(")
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
 
 
 @dataclasses.dataclass(frozen=True)
 class HloOp:
-    """One parsed HLO instruction: opcode, result shapes, raw line."""
+    """One parsed HLO instruction: opcode, result shapes, raw line (cut at
+    200 characters) and the ``op_name`` of its metadata (``None`` when the
+    compiler left the instruction without one)."""
     opcode: str
     result_name: str
     # ((dtype, (dims...)), ...): every dtype[...] in the result type -- one
     # entry for plain results, several for tuple-shaped (-start) results.
     result_shapes: tuple
     line: str
+    op_name: str | None = None
 
     def shapes(self) -> tuple:
         """Just the dim tuples, dtype dropped."""
@@ -222,8 +228,10 @@ def parse_named_ops(hlo_text: str, opcodes: Iterable[str] | None = None) -> list
         opcode = m.group("opcode")
         if wanted is not None and opcode not in wanted:
             continue
+        name = _OP_NAME_RE.search(line)
         ops.append(HloOp(opcode, m.group("result"),
-                         _parse_shapes(m.group("type")), line.strip()[:200]))
+                         _parse_shapes(m.group("type")), line.strip()[:200],
+                         name.group(1) if name else None))
     return ops
 
 
@@ -241,3 +249,62 @@ def collective_dtypes(hlo_text: str) -> set:
         for dt, _ in _parse_shapes(m.group("type")):
             dts.add(dt)
     return dts
+
+
+# ---------------------------------------------------------------------------
+# Engine phases -- the named scopes core/engine.py opens around each phase of
+# the s-step loop, read back from every instruction's ``op_name``.
+# ---------------------------------------------------------------------------
+
+PHASE_PREFIX = "engine."
+ENGINE_PHASES = ("bind", "loop", "packet", "reduce", "assemble", "sweep",
+                 "update", "metrics")
+# A scope is one component of the name stack: ``.../engine.sweep/...``, or
+# wrapped by a transform, ``vmap(engine.packet)``; ``;`` joins the names of
+# instructions the compiler merged.
+_PHASE_RE = re.compile(r"(?:^|[/(;])engine\.([a-z]+)(?=$|[/);])")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_CALLS_RE = re.compile(r"\bcalls=%([\w.\-]+)")
+
+
+def op_phase(op_name: str | None) -> str | None:
+    """The innermost ``engine.<phase>`` scope of an ``op_name``, or
+    ``None``.  Innermost wins: the pipelined driver contracts the next
+    step's packet inside the ring reduction, and that contraction is
+    ``packet`` work, not ``reduce``."""
+    found = _PHASE_RE.findall(op_name or "")
+    return found[-1] if found else None
+
+
+def engine_phase_map(hlo_text: str) -> dict[str, str | None]:
+    """``{instruction name: engine phase or None}`` for every instruction of
+    a compiled program's HLO text (``compiled.as_text()``), names without
+    the ``%``.  A profiler trace names its device ops by these instruction
+    names, so this map labels each op of a profile with its phase.
+
+    A fusion the compiler built without any ``op_name`` takes the phase of
+    the instructions it fuses when they name exactly one."""
+    phases: dict[str, str | None] = {}
+    inside: dict[str, set] = {}
+    calls: dict[str, str] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _NAMED_OP_RE.search(line)
+        if not m:
+            continue
+        name = m.group("result").lstrip("%")
+        op_name = _OP_NAME_RE.search(line)
+        phases[name] = op_phase(op_name and op_name.group(1))
+        inside.setdefault(comp, set()).add(phases[name])
+        called = _CALLS_RE.search(line)
+        if op_name is None and called:
+            calls[name] = called.group(1)
+    for name, comp in calls.items():
+        found = inside.get(comp, set()) - {None}
+        if len(found) == 1:
+            phases[name] = found.pop()
+    return phases

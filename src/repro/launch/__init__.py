@@ -1,2 +1,2 @@
-"""Launchers: production mesh construction, the multi-pod dry-run, roofline
-analysis, and the real train/serve drivers."""
+"""Launchers: production mesh construction, the multi-pod dry-run, and the
+real train/serve drivers."""

@@ -5,7 +5,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # exists; tests and benches see 1 device.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape) cell
-on the production meshes and record the artifacts the roofline analysis reads.
+on the production meshes and record what each compile reports.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun [--arch ID|all]

@@ -24,6 +24,14 @@ Retirement is two-level, matching DESIGN.md section 8:
   * between chunks: the host thresholds each tenant's ``residual`` metric
     against that REQUEST's own tolerance and frees the slot, so a converged
     solve stops consuming sweep work while its neighbors keep iterating.
+
+Tracing: :meth:`SolverService.step` writes host spans into the JAX
+profiler's trace when one is being recorded (``solver_service.step`` and,
+nested in it, ``.admit``, ``.sample``, ``.chunk``, ``.copy_out``,
+``.residual``, ``.retire``), on the clock of the device planes; without a
+profiler a span costs one no-op call.  :meth:`SolverService.stats` counts
+steps, requests, compiled-function cache misses and the bytes copied between
+host and device.
 """
 from __future__ import annotations
 
@@ -41,6 +49,11 @@ from repro.serve.slots import SlotTable, bucket_pow2
 
 # The gather operand each formulation layout binds (built once per service).
 _OPERANDS = {"rows": RowMajorOperand, "cols": ColMajorOperand}
+
+
+def _span(phase: str):
+    """The host span ``solver_service.<phase>`` in the profiler's trace."""
+    return jax.profiler.TraceAnnotation(f"solver_service.{phase}")
 
 
 @dataclasses.dataclass
@@ -100,6 +113,9 @@ class SolverService:
         self._solve_cache: dict[tuple, object] = {}
         self._resid_cache: dict[int, object] = {}
         self._key = jax.random.PRNGKey(cfg.seed)
+        self._stats = dict.fromkeys(
+            ("steps", "admitted", "retired", "h2d_bytes", "d2h_bytes",
+             "chunk_compiles"), 0)
 
     # ------------------------------------------------------------- intake --
     def submit(self, y, lam: float, *, tol: float | None = None,
@@ -129,6 +145,41 @@ class SolverService:
         """Admit queued requests, advance every live solve by one chunk,
         retire tenants that hit their tolerance or the iteration cap.
         Returns {rid: ticket} for requests finished this step."""
+        with _span("step"):
+            self._stats["steps"] += 1
+            with _span("admit"):
+                self._admit()
+            live = self.table.active_slots()
+            if not live:
+                return {}
+
+            with _span("sample"):
+                bucket = bucket_pow2(len(live), self.cfg.min_bucket,
+                                     self.cfg.slots)
+                rows = (live + [live[0]] * (bucket - len(live)))[:bucket]
+                active0 = np.zeros((bucket,), bool)
+                active0[:len(live)] = True
+                self._key, k = jax.random.split(self._key)
+                idx = sample_blocks(k, self.form.sample_dim(self.d, self.n),
+                                    self.plan.b, self.cfg.chunk_iters)
+            with _span("chunk"):
+                ws, alphas = self._chunk_fn(bucket)(
+                    self.X, *self._tenants_to_device(rows),
+                    self._to_device(active0), idx)
+            with _span("copy_out"):
+                ws, alphas = self._to_host(ws), self._to_host(alphas)
+                self.ws[live] = ws[:len(live)]
+                self.alphas[live] = alphas[:len(live)]
+                self.iters_run[live] += self.cfg.chunk_iters
+            with _span("residual"):
+                resid = self._to_host(self._resid_fn(bucket)(
+                    self.X, *self._tenants_to_device(rows)))
+            with _span("retire"):
+                finished = self._retire(live, resid)
+            self._step += 1
+            return finished
+
+    def _admit(self) -> None:
         for req in self.table.admit():
             s, p = req.slot, req.payload
             self.ys[s] = p["y"]
@@ -139,33 +190,10 @@ class SolverService:
             self.ws[s] = 0.0
             self.alphas[s] = 0.0
             self.iters_run[s] = 0
-        live = self.table.active_slots()
-        if not live:
-            return {}
+            self._stats["admitted"] += 1
 
-        bucket = bucket_pow2(len(live), self.cfg.min_bucket, self.cfg.slots)
-        rows = (live + [live[0]] * (bucket - len(live)))[:bucket]
-        active0 = np.zeros((bucket,), bool)
-        active0[:len(live)] = True
-
-        self._key, k = jax.random.split(self._key)
-        idx = sample_blocks(k, self.form.sample_dim(self.d, self.n),
-                            self.plan.b, self.cfg.chunk_iters)
-        ws, alphas = self._chunk_fn(bucket)(
-            self.X, jnp.asarray(self.ys[rows]), jnp.asarray(self.lams[rows]),
-            {n_: jnp.asarray(v[rows]) for n_, v in self.coeffs.items()},
-            (jnp.asarray(self.ws[rows]), jnp.asarray(self.alphas[rows])),
-            jnp.asarray(active0), idx)
-        ws, alphas = np.asarray(ws), np.asarray(alphas)
-        self.ws[live] = ws[:len(live)]
-        self.alphas[live] = alphas[:len(live)]
-        self.iters_run[live] += self.cfg.chunk_iters
-
-        resid = np.asarray(self._resid_fn(bucket)(
-            self.X, jnp.asarray(self.ys[rows]), jnp.asarray(self.lams[rows]),
-            {n_: jnp.asarray(v[rows]) for n_, v in self.coeffs.items()},
-            (jnp.asarray(self.ws[rows]), jnp.asarray(self.alphas[rows]))))
-
+    def _retire(self, live: list[int], resid: np.ndarray
+                ) -> dict[int, SolveTicket]:
         finished: dict[int, SolveTicket] = {}
         for i, s in enumerate(live):
             hit_tol = bool(np.isfinite(self.tols[s])
@@ -180,8 +208,34 @@ class SolverService:
                 converged=hit_tol)
             req.out.append(ticket)
             finished[req.rid] = ticket
-        self._step += 1
+            self._stats["retired"] += 1
         return finished
+
+    def _tenants_to_device(self, rows: list[int]) -> tuple:
+        """The bucket's rows of tenant state, copied to the device:
+        ``(ys, lams, coeffs, (ws, alphas))``."""
+        dev = self._to_device
+        return (dev(self.ys[rows]), dev(self.lams[rows]),
+                {n_: dev(v[rows]) for n_, v in self.coeffs.items()},
+                (dev(self.ws[rows]), dev(self.alphas[rows])))
+
+    def _to_device(self, a: np.ndarray) -> jax.Array:
+        self._stats["h2d_bytes"] += a.nbytes
+        return jnp.asarray(a)
+
+    def _to_host(self, x: jax.Array) -> np.ndarray:
+        out = np.asarray(x)
+        self._stats["d2h_bytes"] += out.nbytes
+        return out
+
+    def stats(self) -> dict[str, int]:
+        """Cumulative counts since the service was built: ``steps`` (calls
+        of :meth:`step`), requests ``admitted`` to a slot and ``retired``
+        from one, ``h2d_bytes`` / ``d2h_bytes`` copied between host and
+        device (the tenant state's round trip of every step), and
+        ``chunk_compiles`` (cache misses of the jitted chunk and residual
+        functions: one of each per bucket)."""
+        return dict(self._stats)
 
     def serve(self, max_steps: int | None = None) -> dict[int, SolveTicket]:
         """Run :meth:`step` until the queue and table drain (or
@@ -205,6 +259,7 @@ class SolverService:
         cache the power-of-two padding exists to keep small."""
         key = (bucket, self.formulation, tuple(sorted(self.coeffs)))
         if key not in self._solve_cache:
+            self._stats["chunk_compiles"] += 1
             plan = dataclasses.replace(self.plan, tenants=bucket)
             chunk = self.cfg.chunk_iters
 
@@ -221,6 +276,8 @@ class SolverService:
     def _resid_fn(self, bucket: int):
         key = (bucket, self.formulation, tuple(sorted(self.coeffs)))
         if key not in self._resid_cache:
+            self._stats["chunk_compiles"] += 1
+
             def fn(X, ys, lams, coeffs, carries):
                 return batched_residuals(
                     self.formulation, X,

@@ -2,6 +2,7 @@
 (tests/test_distributed.py drives this; the main pytest process keeps 1
 device).  Each check asserts internally and exits nonzero on failure."""
 import os
+import re
 import sys
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -345,11 +346,72 @@ def check_elastic_reshard():
     print("elastic_reshard OK")
 
 
+def check_engine_phases():
+    """The engine's named phases in the sharded programs: every working
+    instruction of every loop body of the psum and ring drivers (single and
+    batched) carries exactly one ``engine.*`` phase, the wire's collectives
+    are ``reduce`` work -- except the ring's overlapped next packet, which
+    is ``packet`` -- and the shard-local operand layout is ``bind``.  The
+    index stream is an argument, as the benchmark passes it: the sampler's
+    own loops are not the engine's."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from _phases import work_phases
+    from repro.core import (FORMULATIONS, SolverPlan, TenantBatch,
+                            make_solver_mesh, s_step_solve_batched_sharded,
+                            s_step_solve_sharded)
+    from repro.core.hlo_analysis import (engine_phase_map, op_phase,
+                                         parse_named_ops)
+    mesh = make_solver_mesh(8)
+    b, s, iters, f64 = 4, 2, 6, jnp.float64
+
+    def sds(shape, spec, dtype=f64):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def lower(form, wire, d, n, tenants=None):
+        xspec, yspec, _ = FORMULATIONS[form].dist_in_specs("shards")
+        dim = d if form == "primal" else n
+        idx = sds((iters, b), P(), jnp.int32)
+        X = sds((d, n), xspec)
+        if tenants is None:
+            plan = SolverPlan(b=b, s=s, wire=wire)
+            return jax.jit(lambda X, y, idx: s_step_solve_sharded(
+                form, plan, mesh, X, y, 1e-3, iters, idx=idx)).lower(
+                X, sds((n,), yspec), idx).compile(), dim
+        plan = SolverPlan(b=b, s=s, wire=wire, tenants=tenants)
+        return jax.jit(lambda X, ys, lams, idx: s_step_solve_batched_sharded(
+            form, plan, mesh, X, TenantBatch(ys, lams), iters, idx=idx)
+        ).lower(X, sds((tenants, n), P()), sds((tenants,), P()),
+                idx).compile(), dim
+
+    body = {"loop", "packet", "reduce", "assemble", "sweep", "update"}
+    programs = {f"{form} {wire}": lower(form, wire, d, n)[0]
+                for form, d, n in (("primal", 64, 256), ("dual", 256, 64))
+                for wire in ("psum", "ring")}
+    programs.update({f"batched {wire}": lower("primal", wire, 64, 256, 4)[0]
+                     for wire in ("psum", "ring")})
+    for what, comp in programs.items():
+        text = comp.as_text()
+        counts = work_phases(text, strict=False)
+        # The batched init's per-tenant map may be a loop of its own: bind.
+        assert body <= set(counts) - {"compiler-made"} <= body | {"bind"}, (
+            what, counts)
+        assert "bind" in engine_phase_map(text).values(), what
+        if what in ("primal ring", "dual ring"):
+            # The next step's packet contracted between the ring's phases.
+            nested = [op for op in parse_named_ops(text) if re.search(
+                r"engine\.reduce/.*engine\.packet", op.op_name or "")]
+            assert nested and all(op_phase(op.op_name) == "packet"
+                                  for op in nested), what
+        print(f"  {what}: {dict(counts)}")
+    print("engine_phases OK")
+
+
 CHECKS = {f.__name__.replace("check_", ""): f for f in
           (check_solver_equivalence, check_pipelined_wire,
            check_collective_counts, check_collective_counts_pallas,
            check_batched_collectives, check_flash_decode,
-           check_elastic_reshard)}
+           check_elastic_reshard, check_engine_phases)}
 
 if __name__ == "__main__":
     CHECKS[sys.argv[1]]()

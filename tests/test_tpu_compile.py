@@ -108,3 +108,26 @@ def test_full_solve_fits_one_chip(one_chip, form, layout):
         op, _sds((n,), one_chip), _sds((64, 16), one_chip, jnp.int32))
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.01 * mem.argument_size_in_bytes
+
+
+@pytest.mark.parametrize("form,layout", [("primal", "rows"),
+                                         ("dual", "cols")])
+def test_full_solve_phases(one_chip, form, layout):
+    """The engine's phases survive the TPU compiler at full size: every
+    working instruction of every loop body of the benchmark's solve (a
+    1,280-iteration primal, a 256-iteration dual, b = s = 16) carries
+    exactly one ``engine.*`` phase (``tests/_phases.py``), with none left
+    to the compiler."""
+    from _phases import work_phases
+    op = _operand(layout, one_chip)
+    d, n = op.shape
+    iters = 1280 if form == "primal" else 256
+    plan = SolverPlan(b=16, s=16, impl="pallas")
+    compiled = _assert_kernel(
+        lambda op, y, idx: s_step_solve(form, plan, op, y, 1e-6, iters,
+                                        idx=idx),
+        op, _sds((n,), one_chip), _sds((iters, 16), one_chip, jnp.int32))
+    counts = work_phases(compiled.as_text())
+    assert set(counts) == {"loop", "packet", "assemble", "sweep", "update",
+                           "metrics"}, counts
+    assert counts["loop"] == 1          # the index block's slice
