@@ -556,17 +556,32 @@ class _BoundDual:
         return w, alpha
 
     def metrics(self, carry):
-        # Primal objective evaluated at the dual-generated primal iterate w:
-        # X^T w is O(dn), affordable at the paper's figure sizes; the
-        # distributed fast path skips metrics entirely.
+        # Primal objective evaluated at the dual-generated primal iterate w.
+        # X^T w is O(dn): a full pass over the operand.  The local solve's
+        # history therefore goes through ``metrics_stacked``, one pass per
+        # outer step for all of its s_k iterates; this one-iterate form
+        # serves the tenant-batched engine's residual (local mode only: the
+        # distributed fast path skips metrics, and the HLO pass verifies its
+        # lowering is transpose-free).
         w, alpha = carry
-        n = self.n
-        # Metric evaluation on the full X (local mode only; the distributed
-        # fast path skips metrics and the HLO pass verifies its lowering is
-        # transpose-free).
         xtw = (self.operand.full_matvec(w) if self.X is None
                # contract: allow-transpose -- metric on the raw X
                else self.X.T @ w)
+        return self._metrics_at(xtw, w, alpha)
+
+    def metrics_stacked(self, ws, alphas):
+        """``metrics`` of the s_k iterates of one outer step, rows of ws
+        (s_k, d) and alphas (s_k, n), each series of length s_k.  X^T w_j
+        for every j comes from ONE contraction over the operand, so the
+        history reads X once per outer step instead of s_k times; each
+        metric is still evaluated from its own iterate."""
+        xtws = (self.operand.full_matmat(ws) if self.X is None
+                else jnp.matmul(ws, self.X,
+                                precision=jax.lax.Precision.HIGHEST))
+        return jax.vmap(self._metrics_at)(xtws, ws, alphas)
+
+    def _metrics_at(self, xtw, w, alpha):
+        n = self.n
         r = xtw - self.y
         m = {"objective": 0.5 / n * (r @ r) + 0.5 * self.lam * (w @ w),
              # ||X^T w - alpha - y|| -> 0 at the dual optimum (alpha tracks
@@ -1081,15 +1096,22 @@ def _outer_step(bound: BoundFormulation, plan: SolverPlan, s_k: int, carry,
             return bound.update(carry, flat, dxs, pp), gstate, None
 
     # Metric path: reconstruct the per-inner-iteration trajectory locally.
-    # The inner scan exists for the history, so its own work is metrics.
+    # The inner scan exists for the history, so its own work is metrics.  A
+    # formulation whose metrics read the whole operand (the dual's X^T w)
+    # supplies ``metrics_stacked``: the scan then stacks the s_k iterates
+    # (w, alpha), and their metrics come from one pass over the operand.
+    stacked = getattr(bound, "metrics_stacked", None)
+
     def inner(c, j):
         sl = jax.lax.dynamic_slice_in_dim
         with _phase("update"):
             c = bound.update(c, sl(flat, j * b, b), sl(dxs, j * b, b), pp)
-        return c, bound.metrics(c)
+        return c, (c[:2] if stacked else bound.metrics(c))
 
     with _phase("metrics"):
         carry, hist = jax.lax.scan(inner, carry, jnp.arange(s_k))
+        if stacked:
+            hist = stacked(*hist)
         if plan.track_cond:
             # Fig. 4i conditions the scaled packet with its ridge diagonal
             # (scale*G + reg*I) -- the quantity the kernel used to emit when

@@ -26,7 +26,8 @@ shape (m, C):
 ``Y Y^T = K[flat][:, flat]`` and ``Y u = K[flat, :] u`` -- the kernel trick.)
 The gather operands also offer the same products over EVERY sample,
 ``full_apply(v)`` (``Y_all^T v``) and ``full_matvec(t)`` (``Y_all t``),
-computed densely for warm starts and metrics.
+computed densely for warm starts and metrics; the column operand adds
+``full_matmat(T)``, ``full_matvec`` of several vectors in one pass.
 
 Padding and relayout happen ONCE, when the operand is built (a physical
 copy of the dataset); the kernels then read the stored array as is.  Build
@@ -331,6 +332,13 @@ class ColMajorOperand(_GatherOperand):
         out = jnp.matmul(tp, self.array, precision=_HI,
                          preferred_element_type=_acc_dtype(self.dtype))
         return out[:self.samples].astype(self.dtype)
+
+    def full_matmat(self, T):
+        """X^T t for every row t of T (k, C), in one pass over X: out(k, S)."""
+        Tp = jnp.pad(T, ((0, 0), (0, self.c_pad - T.shape[1])))
+        out = jnp.matmul(Tp, self.array, precision=_HI,
+                         preferred_element_type=_acc_dtype(self.dtype))
+        return out[:, :self.samples].astype(self.dtype)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,8 @@
 """Engine phases read back from a compiled program's HLO, for the tracing
 tests: the instructions of every loop body (scan, ``lax.map``, cond
-branches), and the phase of each one that does work."""
+branches), the phase of each one that does work, and where a phase reads
+an operand-sized array."""
+import math
 import re
 from collections import Counter
 
@@ -31,29 +33,36 @@ def _computations(text):
     return comps
 
 
-def loop_body_lines(text):
-    """Instruction lines of every while body reachable from the entry, and
-    of the conditional branches inside them (fused computations and
-    reduction regions aside: their fusion or reduce is the instruction)."""
+def _reachable(text):
+    """``(computation, depth, line)`` for every instruction line of the
+    entry (depth 0), of every while body reachable from it (one deeper than
+    the loop that runs it) and of the conditional branches inside them (the
+    depth of their conditional).  Fused computations and reduction regions
+    aside: their fusion or reduce is the instruction."""
     comps = _computations(text)
     entry = re.search(r"^ENTRY %([\w.\-]+)", text, re.M).group(1)
     calls = re.compile(r"\bbody=%([\w.\-]+)|_computation=%([\w.\-]+)"
                        r"|branch_computations=\{([^}]*)\}")
-    todo, seen = [entry], set()
-    out = []
+    todo, seen = [(entry, 0)], set()
     while todo:
-        comp = todo.pop()
+        comp, depth = todo.pop()
         if comp in seen:
             continue
         seen.add(comp)
-        if comp != entry:
-            out += comps[comp]
         for line in comps[comp]:
+            yield comp, depth, line
             for body, branch, branches in calls.findall(line):
-                todo += [c for c in (body, branch) if c]
-                todo += [b.strip().lstrip("%") for b in branches.split(",")
-                         if b.strip()]
-    return out
+                if body:
+                    todo.append((body, depth + 1))
+                todo += [(b.strip().lstrip("%"), depth)
+                         for b in [branch, *branches.split(",")] if b.strip()]
+
+
+def loop_body_lines(text):
+    """Instruction lines of every while body reachable from the entry, and
+    of the conditional branches inside them."""
+    entry = re.search(r"^ENTRY %([\w.\-]+)", text, re.M).group(1)
+    return [line for comp, _, line in _reachable(text) if comp != entry]
 
 
 def work_phases(text, *, strict=True):
@@ -83,3 +92,37 @@ def work_phases(text, *, strict=True):
             assert LOOP_OPS.search(op_name or ""), (name, op_name)
         counts[phase] += 1
     return counts
+
+
+_SHAPE = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = [a-z]\w*\[([\d,]*)\]")
+_ARGS = re.compile(r"\s[a-z][a-z0-9\-]*\(([^)]*)\)")
+
+
+def _numel(text):
+    """``{instruction name: element count}`` of every array-valued one."""
+    out = {}
+    for line in text.splitlines():
+        m = _SHAPE.match(line)
+        if m:
+            out[m.group(1)] = math.prod(int(x) for x in m.group(2).split(",")
+                                        if x)
+    return out
+
+
+def operand_reads(text, numel, phase):
+    """``{(depth, computation): count}`` of the working instructions in
+    ``phase`` that take an argument of at least ``numel`` elements -- a
+    pass over the operand.  Depth 0 is the entry, 1 the outer scan's body,
+    2 a loop inside it (``_reachable``)."""
+    sizes = _numel(text)
+    phases = engine_phase_map(text)
+    out = Counter()
+    for comp, depth, line in _reachable(text):
+        name, opcode = _INST.match(line).groups()
+        if opcode not in WORK or phases.get(name) != phase:
+            continue
+        args = _ARGS.search(line[line.index(" = "):]).group(1)
+        if any(sizes.get(a.strip().lstrip("%"), 0) >= numel
+               for a in args.split(",")):
+            out[depth, comp] += 1
+    return out

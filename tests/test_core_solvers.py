@@ -88,6 +88,9 @@ def test_ca_bdcd_exact_equivalence(problem, b, s):
     r_ca = ca_bdcd(X, y, LAM, b, s, iters, None, idx=idx, w_ref=w_opt)
     np.testing.assert_allclose(r_ca.w, r_cl.w, rtol=1e-11, atol=1e-13)
     np.testing.assert_allclose(r_ca.alpha, r_cl.alpha, rtol=1e-11, atol=1e-13)
+    for k in ("objective", "residual"):
+        np.testing.assert_allclose(r_ca.history[k], r_cl.history[k],
+                                   rtol=1e-9, atol=0)
 
 
 def test_dual_reaches_primal_solution(problem):

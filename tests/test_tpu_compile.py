@@ -131,3 +131,25 @@ def test_full_solve_phases(one_chip, form, layout):
     assert set(counts) == {"loop", "packet", "assemble", "sweep", "update",
                            "metrics"}, counts
     assert counts["loop"] == 1          # the index block's slice
+
+
+@pytest.mark.parametrize("form,layout", [("primal", "rows"),
+                                         ("dual", "cols")])
+def test_full_solve_metrics_read_x_once(one_chip, form, layout):
+    """At full size, the dual's history reads X in ONE contraction per outer
+    step (the outer scan's body, depth 1), none inside the inner scan; the
+    primal's metrics (from the maintained alpha) never read it."""
+    from _phases import operand_reads
+    op = _operand(layout, one_chip)
+    d, n = op.shape
+    plan = SolverPlan(b=16, s=16, impl="pallas")
+    compiled = _assert_kernel(
+        lambda op, y, idx: s_step_solve(form, plan, op, y, 1e-6, 64,
+                                        idx=idx),
+        op, _sds((n,), one_chip), _sds((64, 16), one_chip, jnp.int32))
+    reads = operand_reads(compiled.as_text(), op.array.size, "metrics")
+    if form == "primal":
+        assert not reads, reads
+    else:
+        assert list(reads.values()) == [1] and next(iter(reads))[0] == 1, \
+            reads
