@@ -1444,7 +1444,10 @@ def s_step_solve_sharded(formulation: Formulation | str, plan: SolverPlan,
     """Distributed s-step solve: the SAME driver as :func:`s_step_solve`,
     wrapped in ``shard_map`` with the formulation's 1D layout.  The only
     behavioural differences are the inserted packet all-reduce (one per outer
-    iteration) and the skipped metric reconstruction.  Returns ``(w, alpha)``
+    iteration) and the skipped metric reconstruction: the sharded solve takes
+    the fast-path update (all ``s * b`` sampled rows of an outer step applied
+    in one deferred call, not block by block) and returns no per-iteration
+    history, so no ``engine.metrics`` phase runs.  Returns ``(w, alpha)``
     with the formulation's output sharding -- or ``(w, alpha, metrics)`` when
     ``plan.guard`` is set (the replicated guard telemetry, same keys as the
     local solve's ``SolveResult.metrics``).
