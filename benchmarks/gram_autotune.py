@@ -4,9 +4,10 @@
 ``REPRO_GRAM_TUNING`` env var).
 
 Both operand layouts are swept through ``gram_packet_sampled``: the
-row-sampled packet of the primal's ``RowMajorOperand`` (the row-slab gather
-kernel) and the column-sampled packet of the dual's transpose-free
-``ColMajorOperand`` (the lane-slab gather kernel).  Only tiles the chip's
+row-sampled packet of ``RowMajorOperand`` (the row-slab gather kernel, which
+the primal and the build-once dual operand run) and the column-sampled
+packet of the sharded dual's shard-local ``_ColGatherOperand`` (the
+lane-slab gather kernel).  Only tiles the chip's
 compiler accepts (``tuning.tile_problems``) are candidates.  Three-field
 table keys load unchanged, defaulting to row-major.
 
@@ -27,8 +28,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.gram import (ColMajorOperand, RowMajorOperand,
-                                gram_packet_sampled, tuning)
+from repro.kernels.gram import RowMajorOperand, gram_packet_sampled, tuning
+from repro.kernels.gram.operands import _ColGatherOperand
 
 from ._util import row, timed
 
@@ -63,8 +64,8 @@ def _timed_case(m: int, n: int, dtype, layout: str, impl: str, bm: int,
         op = RowMajorOperand(jax.random.normal(jax.random.key(0), (pool, n),
                                                dtype))
     else:
-        op = ColMajorOperand(jax.random.normal(jax.random.key(0), (n, pool),
-                                               dtype))
+        op = _ColGatherOperand(jax.random.normal(jax.random.key(0),
+                                                 (n, pool), dtype))
     u = jax.random.normal(jax.random.key(1), (n,), dtype)
     flat = jax.random.randint(jax.random.key(2), (m,), 0, pool, jnp.int32)
     fn = jax.jit(lambda op, flat, u: gram_packet_sampled(

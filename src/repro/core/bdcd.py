@@ -19,22 +19,24 @@ CA identity: the inner loop is block forward substitution against
 with base_j = (1/n) (Y_j^T w_sk - alpha_sk[idx_j] - y[idx_j]); diagonal blocks
 of A are the Theta_{sk+j} of Eq. (18).
 
-Data flow (panel-free since PR 2, transpose-free since PR 5): the dual
-samples *columns* of X, and the formulation binds a column-major
-:class:`~repro.kernels.gram.ColMajorOperand` over the ORIGINAL (d, n) array
--- no ``X.T`` anywhere in the solve path, constructor or scan.  The sampled
-Gram ``Y^T Y`` for ``Y = X[:, flat]`` comes straight from (X, flat) via the
-lane-aligned column-tile kernels (``kernels/gram/sampled_colmajor.py``), and
-the deferred primal updates (Eq. 15/19, ``w -= Y das / (lam n)``) use
-``panel_apply`` on the same operand (``X[:, flat] @ das``).
+Data flow (panel-free): the dual samples *columns* of X, and the
+formulation binds a column-major PacketOperand; which one depends on where
+it is built.  Built once (``bind``: a raw X, or a prebuilt
+:class:`~repro.kernels.gram.ColMajorOperand`), the operand holds X^T as
+row slabs, transposed once, block by block, when it is built; the sampled
+Gram ``Y^T Y`` for ``Y = X[:, flat]`` and the deferred primal updates
+(Eq. 15/19, ``w -= Y das / (lam n)``) then run the row-DMA kernels
+(``kernels/gram/sampled_kernel.py``), which read each sampled column's
+bytes once.  Built inside the sharded or pipelined body on every call
+(``bind_shard``), the shard keeps its ORIGINAL (dl, n) layout -- no
+transpose in the solve body, the ``operand_transpose_free`` contract -- and
+its columns come from the lane-aligned column-tile kernels
+(``kernels/gram/sampled_colmajor.py``), whose slab fetches over-read by the
+128-lane width (``cost_model.packet_hbm_bytes(layout="cols")``).
 
-Tradeoff: PRs 2-4 pre-transposed each shard (``Xl.T``) so column sampling
-became row sampling -- row-contiguous DMA, but a second resident copy of the
-dataset for the length of the solve.  The column-gather operand drops that
-copy; its slab fetches over-read by the 128-lane width (worst case, no
-lane-group dedup), which ``cost_model.packet_hbm_bytes(layout="cols")``
-models and ``make bench-smoke`` records next to the halved resident
-footprint.
+Either way the operand is the one resident copy of the dataset: an earlier
+design pre-transposed each shard (``Xl.T``) inside the solve, a second copy
+for its whole length.
 """
 from __future__ import annotations
 

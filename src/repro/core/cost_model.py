@@ -343,7 +343,7 @@ def overlap_ratio(machine: MachineModel, *, d: int, n: int, axis_sizes,
 # points (sb <= bm=128 => B=1) and fading as sb/bm grows -- which is why the
 # tuning table keeps bm at the sb it can afford in VMEM.
 #
-# Column-major gather (layout="cols", the dual's transpose-free operand): the
+# Column-major gather (layout="cols", the sharded dual's shard operand): the
 # kernel fetches each sampled column as a lane-aligned (bk x LANE) slab of
 # the ORIGINAL layout and selects the target lane in VMEM, so every panel
 # crossing over-reads by the lane width -- ``lane`` x the useful column bytes

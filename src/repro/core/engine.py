@@ -16,9 +16,10 @@ into
   the operand is a :class:`~repro.kernels.gram.PacketOperand` (array +
   layout + gather strategy, DESIGN.md section 5.2), so "which axis is
   sampled and how" is the operand's business, not the engine's: the primal
-  binds row-major X, the dual binds COLUMN-major X in its original (d, n)
-  layout (no pre-transpose), and a pre-materialized kernel matrix binds
-  through the same dispatch with zero engine edits;
+  binds row-major X, the dual binds COLUMN-major X (built once as the row
+  slabs of X^T; a shard inside the sharded body gathers the columns of its
+  original (dl, n) layout, with no transpose), and a pre-materialized
+  kernel matrix binds through the same dispatch with zero engine edits;
 * a :class:`SolverPlan`: the execution knobs (b, s, backend ``impl``, kernel
   ``tiles``, ``fuse_packet``, ``unroll``, ``track_cond``) -- ``s=1`` *is* the
   classical variant, not a separate loop;
@@ -58,6 +59,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.kernels.gram import (ColMajorOperand, PacketOperand, PacketPlan,
                                 RowMajorOperand, gram_packet_sampled,
                                 panel_apply, panel_matvec)
+from repro.kernels.gram.operands import _ColGatherOperand
 from repro.kernels.gram.ops import _check_positive_int, _pad_axis
 
 from .hlo_analysis import ENGINE_PHASES, PHASE_PREFIX
@@ -448,11 +450,13 @@ class PrimalRidge:
 @dataclasses.dataclass(frozen=True)
 class _BoundDual:
     """Algorithm 3/4 hooks; ``operand`` is the column-major X (d, n) -- or a
-    row shard Xl (dl, n) -- in its ORIGINAL layout.  The dual samples
-    *columns* of X; the column-gather operand (``sampled_colmajor.py``) makes
-    that a first-class access pattern, so no pre-transpose and no second
-    resident copy of the dataset exist anywhere in the dual solve path
-    (the PR-2..4 ``Xl.T`` workaround this replaces is discussed in
+    row shard Xl (dl, n).  The dual samples *columns* of X.  The build-once
+    operand (``bind``) holds X^T as row slabs, transposed once when it is
+    built, and runs the row-DMA kernels; the shard-local operand
+    (``bind_shard``, built inside the sharded body on every call) gathers
+    columns of the ORIGINAL (dl, n) layout (``sampled_colmajor.py``), so the
+    sharded solve body holds no transpose of the operand.  Either way the
+    operand is the one resident copy of the dataset (see
     ``repro.core.bdcd``).
 
     Packet: Theta = Y^T Y / (lam n^2) + I/n with Y = X[:, flat] plus the RAW
@@ -595,8 +599,9 @@ class _BoundDual:
 
 
 class DualRidge:
-    """(CA-)BDCD: samples data points (columns of X) from the ORIGINAL
-    (d, n) layout via the column-major operand; 1D-block-row layout."""
+    """(CA-)BDCD: samples data points (columns of X) through a column-major
+    operand -- X^T in row slabs when bound once, the original (dl, n) shard
+    gathered in place inside the sharded body; 1D-block-row layout."""
     name = "dual"
     operand_layout = "cols"
 
@@ -620,8 +625,10 @@ class DualRidge:
         return {"scale_c": 1.0 / (lam * n * n), "lam_n": lam * n}
 
     def bind(self, X, y, lam, *, x0=None, w_ref=None):
-        # A prebuilt ColMajorOperand serves init and metrics itself; a raw X
-        # is kept for them (one extra resident copy of the dataset).
+        # Built once: X^T in row slabs, one transposed copy made here (or
+        # by the caller, which passes a prebuilt ColMajorOperand).  A
+        # prebuilt operand serves init and metrics itself; a raw X is kept
+        # for them (one extra resident copy of the dataset).
         raw = None if isinstance(X, ColMajorOperand) else X
         return _BoundDual(operand=ColMajorOperand.of(X), y=y, lam=lam,
                           n=X.shape[1], X=raw, alpha0=x0, w_ref=w_ref)
@@ -630,10 +637,11 @@ class DualRidge:
         return _pad_to(X, n_shards, 0), y
 
     def bind_shard(self, Xl, yl, lam, *, d, n, x0=None):
-        # The ORIGINAL (dl, n) shard, zero copies: the column-major operand
-        # gathers sampled columns in place (pre-PR-5 this was ``Xl.T``,
-        # doubling the resident dataset for the length of the solve).
-        return _BoundDual(operand=ColMajorOperand(Xl), y=yl, lam=lam, n=n,
+        # Built inside the sharded body on every call, where the operand
+        # may not be transposed (``operand_transpose_free``): the ORIGINAL
+        # (dl, n) shard, padded, its sampled columns gathered in place by
+        # the column-tile kernels.
+        return _BoundDual(operand=_ColGatherOperand(Xl), y=yl, lam=lam, n=n,
                           alpha0=x0)
 
     def dist_in_specs(self, axis):

@@ -283,9 +283,11 @@ def engine_phase_map(hlo_text: str) -> dict[str, str | None]:
     names, so this map labels each op of a profile with its phase.
 
     A fusion the compiler built without any ``op_name`` takes the phase of
-    the instructions it fuses when they name exactly one."""
+    the instructions it fuses when they name exactly one.  Constants count
+    only when nothing else names a phase: XLA merges equal constants, and
+    the one it keeps names whichever op made it first."""
     phases: dict[str, str | None] = {}
-    inside: dict[str, set] = {}
+    inside: dict[str, tuple[set, set]] = {}     # (ops, constants)
     calls: dict[str, str] = {}
     comp = None
     for line in hlo_text.splitlines():
@@ -299,12 +301,14 @@ def engine_phase_map(hlo_text: str) -> dict[str, str | None]:
         name = m.group("result").lstrip("%")
         op_name = _OP_NAME_RE.search(line)
         phases[name] = op_phase(op_name and op_name.group(1))
-        inside.setdefault(comp, set()).add(phases[name])
+        ops, consts = inside.setdefault(comp, (set(), set()))
+        (consts if m.group("opcode") == "constant" else ops).add(phases[name])
         called = _CALLS_RE.search(line)
         if op_name is None and called:
             calls[name] = called.group(1)
     for name, comp in calls.items():
-        found = inside.get(comp, set()) - {None}
+        ops, consts = inside.get(comp, (set(), set()))
+        found = (ops - {None}) or (consts - {None})
         if len(found) == 1:
             phases[name] = found.pop()
     return phases

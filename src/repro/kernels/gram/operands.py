@@ -6,10 +6,16 @@ its GATHER STRATEGY:
 * :class:`RowMajorOperand` -- logical array (S, C), samples are rows; the
   index-prefetched row-DMA kernels of ``sampled_kernel.py``.  Stored in the
   slab layout (S, C_pad/128, 128) those kernels DMA from.
-* :class:`ColMajorOperand` -- logical array (C, S), samples are columns of
-  the ORIGINAL layout; the lane-aligned column-tile kernels of
-  ``sampled_colmajor.py``.  This is what lets ``_BoundDual`` bind X (d, n)
-  with no transpose.  Stored zero-padded to (C_pad, S_pad).
+* :class:`ColMajorOperand` -- logical array (C, S), samples are COLUMNS:
+  the dual's build-once operand over X (d, n).  Stored as the row slabs of
+  X^T, (S, C_pad/128, 128), transposed once when the operand is built, so
+  it runs the same row-DMA kernels (one 4 KB DMA per sampled column block).
+* ``_ColGatherOperand`` -- the same logical (C, S) array stored in its
+  ORIGINAL orientation, zero-padded to (C_pad, S_pad), read by the
+  lane-aligned column-tile kernels of ``sampled_colmajor.py``.  The sharded
+  and pipelined duals bind their shard through it inside the ``shard_map``
+  body on every call, where no operand transpose is allowed (the
+  ``operand_transpose_free`` contract).
 * :class:`MaterializedOperand` -- array (S, S) of ALREADY-FORMED products
   (a kernel matrix K): the "Gram" is a gather, not a contraction.  This is
   the kernel-BDCD prerequisite (arXiv:2406.18001); smoke-proven through the
@@ -26,14 +32,14 @@ shape (m, C):
 ``Y Y^T = K[flat][:, flat]`` and ``Y u = K[flat, :] u`` -- the kernel trick.)
 The gather operands also offer the same products over EVERY sample,
 ``full_apply(v)`` (``Y_all^T v``) and ``full_matvec(t)`` (``Y_all t``),
-computed densely for warm starts and metrics; the column operand adds
+computed densely for warm starts and metrics; ``ColMajorOperand`` adds
 ``full_matmat(T)``, ``full_matvec`` of several vectors in one pass.
 
 Padding and relayout happen ONCE, when the operand is built (a physical
 copy of the dataset); the kernels then read the stored array as is.  Build
 the operand once and pass it where X goes -- the formulations' ``bind``
 accepts it in place of the raw array -- to keep a single resident copy.
-Both gather operands are pytrees, so they pass through ``jax.jit`` as
+The gather operands are pytrees, so they pass through ``jax.jit`` as
 arguments.
 
 Registration IS the protocol: a new operand kind implements these three
@@ -110,6 +116,29 @@ def _row_slabs(x, c_pad: int):
     return jax.lax.fori_loop(0, -(-S // rows), block, out)
 
 
+_SLAB_COLS = 128     # columns of x transposed per step of the X^T slab build
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _col_slabs(x, c_pad: int):
+    """(C, S) -> the slabs of x^T, (S, c_pad/LANE, LANE), zero-padded: the
+    transposing sibling of :func:`_row_slabs`.  Each step slices a block of
+    x's columns, transposes and pads only that block (32 MB at news20's
+    d = 62,061), so no transposed copy of x exists beside the output.  The
+    last block is clamped to end at column S, as in ``_row_slabs``."""
+    S = x.shape[1]
+    cols = min(_SLAB_COLS, S)
+
+    def block(i, out):
+        start = jnp.minimum(i * cols, S - cols)
+        blk = jax.lax.dynamic_slice_in_dim(x, start, cols, 1).T
+        blk = _pad_axis(blk, c_pad, 1).reshape(cols, c_pad // LANE, LANE)
+        return jax.lax.dynamic_update_slice_in_dim(out, blk, start, 0)
+
+    out = jnp.zeros_like(x, shape=(S, c_pad // LANE, LANE))
+    return jax.lax.fori_loop(0, -(-S // cols), block, out)
+
+
 @functools.partial(jax.jit, static_argnums=1)
 def _col_padded(x, c_pad: int):
     C, S = x.shape
@@ -135,7 +164,7 @@ class PacketOperand(Protocol):
 
 
 class _GatherOperand:
-    """Shared storage of the two gather operands: the kernel-layout
+    """Shared storage of the gather operands: the kernel-layout
     ``array`` plus the logical ``(samples, contraction)`` sizes.  A pytree
     whose only leaf is the array."""
     layout: ClassVar[str]
@@ -181,22 +210,13 @@ class _GatherOperand:
         return obj
 
 
-@jax.tree_util.register_pytree_node_class
-class RowMajorOperand(_GatherOperand):
-    """Logical array (S, C); samples rows: Y = X[flat, :].  Stored as the
-    slab layout (S, C_pad/LANE, LANE) the row-DMA gather kernels read
-    (``sampled_kernel.py``): C zero-padded to the layout's contraction tile,
-    then split into 128-lane rows.  The relayout is one copy, made here."""
+class _SlabOperand(_GatherOperand):
+    """The row-DMA kernels' operand (``sampled_kernel.py``): the sample-major
+    array A (S, C) -- the sampled panel is Y = A[flat, :] -- stored as slabs
+    (S, C_pad/LANE, LANE): C zero-padded to the row layout's contraction
+    tile, then split into 128-lane rows.  Subclasses choose how A relates to
+    the array they are built from."""
     layout: ClassVar[str] = "rows"
-
-    def __init__(self, x):
-        S, C = x.shape
-        c_pad = tuning.padded_contraction(C, x.dtype, self.layout)
-        self._set(_row_slabs(x, c_pad), S, C)
-
-    @property
-    def shape(self) -> tuple[int, int]:
-        return (self.samples, self.contraction)
 
     def _panel(self, flat):
         """The sampled rows (m, C_pad), zero-padded columns (ref path)."""
@@ -246,13 +266,13 @@ class RowMajorOperand(_GatherOperand):
         return out[:m]
 
     def full_apply(self, v):
-        """X^T v over every row: out(C)."""
+        """A^T v over every sample: out(C)."""
         out = jnp.einsum("s,sgl->gl", v, self.array, precision=_HI,
                          preferred_element_type=_acc_dtype(self.dtype))
         return out.reshape(-1)[:self.contraction].astype(self.dtype)
 
     def full_matvec(self, t):
-        """X t over every row: out(S)."""
+        """A t over every sample: out(S)."""
         tp = _pad_to(t, self.c_pad).reshape(-1, LANE)
         out = jnp.einsum("sgl,gl->s", self.array, tp, precision=_HI,
                          preferred_element_type=_acc_dtype(self.dtype))
@@ -260,13 +280,57 @@ class RowMajorOperand(_GatherOperand):
 
 
 @jax.tree_util.register_pytree_node_class
-class ColMajorOperand(_GatherOperand):
-    """Logical array (C, S); samples COLUMNS of the original layout:
-    Y = X[:, flat].T.  The lane-aligned column-tile gather kernels -- the
-    dual's operand with no transpose.  Stored zero-padded to (C_pad, S_pad):
-    C to the layout's contraction tile (the kernels' row tiles) and S to the
-    128-lane width (every slab copy in bounds).  Padded index slots clamp to
-    column 0 and only touch G/r rows >= m."""
+class RowMajorOperand(_SlabOperand):
+    """Logical array X (S, C); samples rows: Y = X[flat, :].  The slabs are
+    X's rows; the relayout is one copy, made here."""
+
+    def __init__(self, x):
+        S, C = x.shape
+        c_pad = tuning.padded_contraction(C, x.dtype, self.layout)
+        self._set(_row_slabs(x, c_pad), S, C)
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.samples, self.contraction)
+
+
+@jax.tree_util.register_pytree_node_class
+class ColMajorOperand(_SlabOperand):
+    """Logical array X (C, S); samples COLUMNS: Y = X[:, flat].T.  The
+    dual's build-once operand: stored as the row slabs of X^T, so its
+    packets, applies and matvecs run the row-DMA kernels.  The transpose is
+    the one copy, made here block by block (:func:`_col_slabs`); the result
+    equals ``RowMajorOperand(X.T).array`` bit for bit."""
+
+    def __init__(self, x):
+        C, S = x.shape
+        c_pad = tuning.padded_contraction(C, x.dtype, self.layout)
+        self._set(_col_slabs(x, c_pad), S, C)
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.contraction, self.samples)
+
+    def full_matmat(self, T):
+        """X^T t for every row t of T (k, C), in one pass over the slabs:
+        out(k, S)."""
+        Tp = jnp.pad(T, ((0, 0), (0, self.c_pad - T.shape[1])))
+        out = jnp.einsum("kgl,sgl->ks", Tp.reshape(T.shape[0], -1, LANE),
+                         self.array, precision=_HI,
+                         preferred_element_type=_acc_dtype(self.dtype))
+        return out.astype(self.dtype)
+
+
+@jax.tree_util.register_pytree_node_class
+class _ColGatherOperand(_GatherOperand):
+    """Logical array X (C, S); samples COLUMNS of the original layout:
+    Y = X[:, flat].T, through the lane-aligned column-tile gather kernels
+    (``sampled_colmajor.py``) -- the shard-local dual operand, built inside
+    the sharded solve's body on every call, where no transpose of the
+    operand is allowed.  Stored zero-padded to (C_pad, S_pad): C to the
+    layout's contraction tile (the kernels' row tiles) and S to the 128-lane
+    width (every slab copy in bounds).  Padded index slots clamp to column 0
+    and only touch G/r rows >= m."""
     layout: ClassVar[str] = "cols"
 
     def __init__(self, x):
@@ -332,13 +396,6 @@ class ColMajorOperand(_GatherOperand):
         out = jnp.matmul(tp, self.array, precision=_HI,
                          preferred_element_type=_acc_dtype(self.dtype))
         return out[:self.samples].astype(self.dtype)
-
-    def full_matmat(self, T):
-        """X^T t for every row t of T (k, C), in one pass over X: out(k, S)."""
-        Tp = jnp.pad(T, ((0, 0), (0, self.c_pad - T.shape[1])))
-        out = jnp.matmul(Tp, self.array, precision=_HI,
-                         preferred_element_type=_acc_dtype(self.dtype))
-        return out[:, :self.samples].astype(self.dtype)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -190,10 +190,11 @@ def gram_packet_sampled(X, flat: jax.Array, u: jax.Array, *,
     ``flat`` (m,) int indices (duplicates allowed), ``u`` of the operand's
     contraction length.
 
-    The operand owns the gather: row-major scalar-prefetches ``flat`` and
-    streams sampled rows HBM->VMEM inside the kernel; column-major gathers
-    lane-aligned column tiles of the original layout; materialized operands
-    gather the already-formed products.  Padding is exact in every layout
+    The operand owns the gather: the row-slab operands (row-major, and the
+    column-major one stored as the slabs of X^T) scalar-prefetch ``flat``
+    and stream sampled rows HBM->VMEM inside the kernel; the shard-local
+    column gather fetches lane-aligned column tiles of the original layout;
+    materialized operands gather the already-formed products.  Padding is exact in every layout
     (padded contraction entries are zero and padded index slots only touch
     G/r rows >= m, which are sliced off before the regularized diagonal can
     leak).

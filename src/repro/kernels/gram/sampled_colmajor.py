@@ -1,9 +1,12 @@
 """Column-tile sampled Pallas kernels: the dual layout without the pre-transpose.
 
-The dual methods (Algorithms 3/4) sample *columns* of X.  Binding ``X.T``
-would turn that into the row-sampled kernels of ``sampled_kernel.py`` at the
-cost of a second resident copy of the dataset for the whole solve.  The
-kernels here gather column tiles of the ORIGINAL (d, n) layout instead:
+The dual methods (Algorithms 3/4) sample *columns* of X.  An operand built
+once (``ColMajorOperand``) stores X^T as row slabs and runs the row-sampled
+kernels of ``sampled_kernel.py``.  A shard bound inside the sharded or
+pipelined solve body on every call cannot be transposed there (the
+``operand_transpose_free`` contract), so its operand (``_ColGatherOperand``)
+keeps the ORIGINAL (dl, n) layout, and the kernels here gather its column
+tiles:
 
 * ``gram_packet_sampled_cols_pallas``: the fused dual packet
   ``(G = scale * Y^T Y + reg*I, r = scale_r * Y^T u)`` for ``Y = X[:, flat]``
@@ -25,8 +28,8 @@ slice the (8, 128) HBM tiling refuses.  Instead each sampled column
 ``c % LANE`` is selected out of the slab in VMEM (one-hot mask + lane-sum,
 no arithmetic on the values, so the extracted panel is bitwise the gathered
 column).  The slab fetch over-reads by the lane width: LANE x the useful
-column bytes, the per-iteration traffic this layout trades for dropping the
-2x resident footprint (``cost_model.packet_hbm_bytes(layout="cols")`` carries
+column bytes, the per-iteration traffic this layout pays for reading the
+shard with no transpose (``cost_model.packet_hbm_bytes(layout="cols")`` carries
 the term; sampled columns sharing a lane group are NOT deduplicated -- the
 model is the worst case).
 

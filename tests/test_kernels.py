@@ -13,6 +13,7 @@ from repro.kernels.gram import (ColMajorOperand, gram, gram_packet,
                                 gram_packet_sampled_cols_ref,
                                 gram_packet_sampled_ref, panel_apply,
                                 panel_apply_cols_ref, tuning)
+from repro.kernels.gram.operands import _ColGatherOperand
 
 SHAPES = [(128, 512), (64, 300), (96, 1024), (8, 128), (130, 700), (256, 256)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -80,18 +81,21 @@ def test_gram_packet_sampled_matches_ref(shape, dtype):
     np.testing.assert_allclose(r1, r0, rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("cols", [ColMajorOperand, _ColGatherOperand],
+                         ids=["slabs", "gather"])
 @pytest.mark.parametrize("shape", [(96, 512), (40, 300), (13, 128)])
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
-def test_gram_packet_sampled_cols_matches_ref(shape, dtype):
-    """The lane-slab column-gather kernel vs the jnp oracle: m sampled
-    columns of a (d, n) operand in its ORIGINAL layout, including
-    out-of-order and duplicate indices and ragged (m, d, n)."""
+def test_gram_packet_sampled_cols_matches_ref(shape, dtype, cols):
+    """Both column operands vs the jnp oracle: m sampled columns of a (d, n)
+    X -- the row kernel on the slabs of X^T, the lane-slab column-gather
+    kernel on X's ORIGINAL layout -- including out-of-order and duplicate
+    indices and ragged (m, d, n)."""
     m, d = shape
     pool = 2 * max(m, 16) + 5           # ragged column count (n % 128 != 0)
     X = jax.random.normal(jax.random.key(20), (d, pool), dtype)
     u = jax.random.normal(jax.random.key(21), (d,), dtype)
     flat = jax.random.randint(jax.random.key(22), (m,), 0, pool, jnp.int32)
-    G1, r1 = gram_packet_sampled(ColMajorOperand(X), flat, u, scale=1.0 / d,
+    G1, r1 = gram_packet_sampled(cols(X), flat, u, scale=1.0 / d,
                                  reg=0.01, impl="pallas_interpret")
     G0, r0 = gram_packet_sampled_cols_ref(X, flat, u, 1.0 / d, 0.01)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -99,7 +103,9 @@ def test_gram_packet_sampled_cols_matches_ref(shape, dtype):
     np.testing.assert_allclose(r1, r0, rtol=tol, atol=tol)
 
 
-def test_panel_apply_cols_matches_ref():
+@pytest.mark.parametrize("cols", [ColMajorOperand, _ColGatherOperand],
+                         ids=["slabs", "gather"])
+def test_panel_apply_cols_matches_ref(cols):
     # f32 (this module runs without the x64 fixture): f32-level tolerances.
     d, pool = 31, 200
     X = jax.random.normal(jax.random.key(23), (d, pool), jnp.float32)
@@ -109,7 +115,7 @@ def test_panel_apply_cols_matches_ref():
     np.testing.assert_allclose(panel_apply_cols_ref(X, flat, v, 0.7), a0,
                                rtol=1e-5, atol=1e-5)
     for impl in ("ref", "pallas_interpret"):
-        a1 = panel_apply(ColMajorOperand(X), flat, v, scale=0.7, impl=impl)
+        a1 = panel_apply(cols(X), flat, v, scale=0.7, impl=impl)
         np.testing.assert_allclose(a1, a0, rtol=1e-5, atol=1e-5)
 
 

@@ -5,10 +5,12 @@ The tentpole claims pinned here:
 
 * the dual engine's iterates are IDENTICAL between the legacy pre-transposed
   operand (PRs 2-4: ``RowMajorOperand(X.T)``, reconstructed outside the
-  engine -- the shipped ``DualRidge`` no longer transposes anything) and the
-  column-gather operand over the original (d, n) layout -- s=1, s>1, ragged
-  tail, sharded, on ref and pallas_interpret, with duplicate and tail-padded
-  column indices;
+  engine) and the shipped ``DualRidge``'s operands -- the build-once row
+  slabs of X^T, and, in the sharded solve, the column-gather operand over
+  the original (d, n) shard -- s=1, s>1, ragged tail, sharded, on ref and
+  pallas_interpret, with duplicate and tail-padded column indices;
+* both column operands (row slabs of X^T, column gather of X) give the
+  transposed row operand's packets, applies and matvecs;
 * a pre-materialized kernel-matrix operand (the kernel-BDCD prerequisite,
   arXiv:2406.18001) registers through the operand layer and drives a full
   engine solve with ZERO engine edits -- the formulation below lives in this
@@ -29,7 +31,8 @@ from repro.data import SyntheticSpec, make_regression
 from repro.kernels.gram import (ColMajorOperand, MaterializedOperand,
                                 PacketOperand, RowMajorOperand, as_operand,
                                 gram_packet_sampled, gram_packet_sampled_ref,
-                                panel_apply, panel_matvec)
+                                panel_apply, panel_matvec, tuning)
+from repro.kernels.gram.operands import _ColGatherOperand
 
 from _legacy_dual import LegacyPreTransposeDual
 from _x64 import x64_mode  # noqa: F401  (autouse fixture)
@@ -77,8 +80,9 @@ def _assert_layout_invariant(impl, a, b):
 @pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
 @pytest.mark.parametrize("s", [1, 3, 5], ids=["s1", "s3", "ragged-s5"])
 def test_dual_layout_invariance(problem, impl, s):
-    """Legacy pre-transposed vs column-gather operand: identical dual
-    iterates (bit-for-bit on the kernel path, see _assert_layout_invariant).
+    """Legacy pre-transposed vs the bound operand (the row slabs of X^T,
+    built block by block from X): identical dual iterates (bit-for-bit on
+    the kernel path, see _assert_layout_invariant).
     s=3 pads the sb=12 tail of every flat to the 16-row tile (tail-padded
     column indices); s=5 with iters=12 adds the ragged final outer
     iteration; the index stream carries duplicates throughout."""
@@ -99,8 +103,9 @@ def test_dual_layout_invariance(problem, impl, s):
 
 @pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
 def test_dual_layout_invariance_sharded(problem, impl):
-    """Same invariance through the shard_map backend (single-device mesh in
-    this process; the 8-device world re-checks it in dist_checks)."""
+    """Same invariance through the shard_map backend, whose shard-local
+    operand is the column gather over the original layout (single-device
+    mesh in this process; the 8-device world re-checks it in dist_checks)."""
     X, y = problem
     mesh = make_solver_mesh(1)
     idx = _dup_idx(jax.random.key(2), N, 4, ITERS)
@@ -115,24 +120,47 @@ def test_dual_layout_invariance_sharded(problem, impl):
 
 
 def test_dual_solver_binds_original_layout(problem):
-    """The shipped dual formulation samples the ORIGINAL (d, n) array: the
-    bound operand is column-major and holds X in its own orientation (zero-
-    padded once to the kernels' tiles), not a transposed copy; a prebuilt
-    operand binds as is, with no second copy."""
+    """The dual formulation's two operands.  ``bind`` (built once) holds the
+    sample-major row slabs of X^T, (n, d_pad/128, 128): padded X^T, the one
+    transposed copy; a prebuilt operand binds as is, with no second copy.
+    ``bind_shard`` (built inside the sharded body on every call) keeps the
+    ORIGINAL (d, n) orientation, zero-padded, for the column gather."""
     X, y = problem
     bound = DualRidge().bind(X, y, LAM)
     assert isinstance(bound.operand, ColMajorOperand)
-    arr = bound.operand.array
-    assert arr.shape[0] >= D and arr.shape[1] >= N
-    np.testing.assert_array_equal(arr[:D, :N], X)
-    assert not np.asarray(arr[D:]).any() and not np.asarray(arr[:, N:]).any()
+    assert bound.operand.shape == (D, N)
     assert bound.operand.samples == N and bound.operand.contraction == D
+    arr = bound.operand.array
+    d_pad = tuning.padded_contraction(D, X.dtype, "rows")
+    assert arr.shape == (N, d_pad // 128, 128)
+    panel = arr.reshape(N, d_pad)
+    np.testing.assert_array_equal(panel[:, :D], X.T)
+    assert not np.asarray(panel[:, D:]).any()
     bound_sh = DualRidge().bind_shard(X, y, LAM, d=D, n=N)
-    assert isinstance(bound_sh.operand, ColMajorOperand)
-    np.testing.assert_array_equal(bound_sh.operand.array, arr)
+    assert isinstance(bound_sh.operand, _ColGatherOperand)
+    gat = bound_sh.operand.array
+    assert gat.shape[0] >= D and gat.shape[1] >= N
+    np.testing.assert_array_equal(gat[:D, :N], X)
+    assert not np.asarray(gat[D:]).any() and not np.asarray(gat[:, N:]).any()
     op = ColMajorOperand(X)
     bound_op = DualRidge().bind(op, y, LAM)
     assert bound_op.operand is op and bound_op.X is None
+
+
+@pytest.mark.parametrize("shape", [(23, 70), (1030, 300), (2049, 129),
+                                   (1100, 128)],
+                         ids=["n<block", "n>block", "n=block+1", "n=block"])
+def test_col_slabs_equal_transposed_row_slabs(shape):
+    """The transposing slab build, block by block, is bitwise the row slabs
+    of X^T: d off the 1,024 contraction unit, n off the 128-column block
+    (smaller, larger, one past) and on it."""
+    d, n = shape
+    X = jax.random.normal(jax.random.key(10), (d, n), jnp.float64)
+    col = ColMajorOperand(X)
+    row = RowMajorOperand(X.T)
+    assert col.array.shape == row.array.shape
+    np.testing.assert_array_equal(col.array, row.array)
+    assert (col.samples, col.contraction) == (n, d) and col.shape == (d, n)
 
 
 # --------------------------------------------------------------------------
@@ -151,9 +179,11 @@ def test_as_operand_raw_array_means_rows(problem):
     assert isinstance(col, PacketOperand)          # runtime protocol check
 
 
-def test_colmajor_matches_transposed_rowmajor(problem):
-    """ColMajorOperand(X) == RowMajorOperand(X.T) on every entry point: the
-    packet, the deferred apply, and the sample-side matvec."""
+@pytest.mark.parametrize("cols", [ColMajorOperand, _ColGatherOperand],
+                         ids=["slabs", "gather"])
+def test_colmajor_matches_transposed_rowmajor(problem, cols):
+    """Both column operands of X == RowMajorOperand(X.T) on every entry
+    point: the packet, the deferred apply, and the sample-side matvec."""
     X, _ = problem
     flat = jnp.asarray([5, 5, 0, 90, 7, 7, 7, 1, 0, 19, 3, 2, 11], jnp.int32)
     u = jax.random.normal(jax.random.key(3), (D,), jnp.float64)
@@ -162,29 +192,32 @@ def test_colmajor_matches_transposed_rowmajor(problem):
         G0, r0 = gram_packet_sampled(RowMajorOperand(X.T), flat, u,
                                      scale=1.0 / N, reg=0.5, scale_r=2.0,
                                      impl=impl)
-        G1, r1 = gram_packet_sampled(ColMajorOperand(X), flat, u,
+        G1, r1 = gram_packet_sampled(cols(X), flat, u,
                                      scale=1.0 / N, reg=0.5, scale_r=2.0,
                                      impl=impl)
         np.testing.assert_allclose(G1, G0, rtol=0, atol=1e-10)
         np.testing.assert_allclose(r1, r0, rtol=0, atol=1e-10)
         a0 = panel_apply(RowMajorOperand(X.T), flat, v, scale=0.7, impl=impl)
-        a1 = panel_apply(ColMajorOperand(X), flat, v, scale=0.7, impl=impl)
+        a1 = panel_apply(cols(X), flat, v, scale=0.7, impl=impl)
         np.testing.assert_allclose(a1, a0, rtol=0, atol=1e-10)
     t = jax.random.normal(jax.random.key(5), (D,), jnp.float64)
     m0 = panel_matvec(RowMajorOperand(X.T), flat, t, scale=1.3, impl="ref")
-    m1 = panel_matvec(ColMajorOperand(X), flat, t, scale=1.3, impl="ref")
+    m1 = panel_matvec(cols(X), flat, t, scale=1.3, impl="ref")
     np.testing.assert_allclose(m1, m0, rtol=0, atol=1e-10)
 
 
-def test_colmajor_ragged_nonaligned(problem):
+@pytest.mark.parametrize("cols", [ColMajorOperand, _ColGatherOperand],
+                         ids=["slabs", "gather"])
+def test_colmajor_ragged_nonaligned(problem, cols):
     """Ragged everything: d not a sublane multiple, n not a lane multiple,
-    duplicate and repeated-0 indices -- pad/unpad is exact in f64."""
+    duplicate and repeated-0 indices -- pad/unpad is exact in f64, for both
+    column operands."""
     d, n = 23, 70
     X = jax.random.normal(jax.random.key(6), (d, n), jnp.float64)
     u = jax.random.normal(jax.random.key(7), (d,), jnp.float64)
     flat = jnp.asarray([5, 5, 0, 22, 7, 7, 7, 1, 0, 19, 3, 2, 11], jnp.int32)
     G0, r0 = gram_packet_sampled_ref(X.T, flat, u, 1.0 / n, 0.5, 2.0)
-    G1, r1 = gram_packet_sampled(ColMajorOperand(X), flat, u, scale=1.0 / n,
+    G1, r1 = gram_packet_sampled(cols(X), flat, u, scale=1.0 / n,
                                  reg=0.5, scale_r=2.0,
                                  impl="pallas_interpret")
     assert G1.shape == (13, 13) and r1.shape == (13,)

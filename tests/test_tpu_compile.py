@@ -2,9 +2,11 @@
 widths, without a chip: the compiler that refuses a misaligned block, an
 illegal tile or an oversized VMEM scratch runs here, on shapes only.
 
-Shapes: the real-sim primal operand (20,958 x 72,309, the row gather) and
-the news20 dual operand (62,061 x 15,935, the column gather), f32, with
-sb = 256 sampled indices (b = 16, s = 16).  Every compile must emit the
+Shapes: the real-sim primal operand (20,958 x 72,309, the row gather), the
+news20 dual operand (62,061 x 15,935) as the row slabs of X^T (the row
+gather) and in its original layout (the column gather, which the sharded
+dual runs on its shard), f32, with sb = 256 sampled indices (b = 16,
+s = 16).  Every compile must emit the
 kernel (``tpu_custom_call``).  The topology is described inside a fixture,
 so only the worker that runs this file loads the TPU compiler.
 """
@@ -16,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import SolverPlan, s_step_solve
 from repro.kernels.gram import (ColMajorOperand, RowMajorOperand, gram_packet,
                                 tuning)
+from repro.kernels.gram.operands import _col_slabs, _ColGatherOperand
 
 REAL_SIM = (20958, 72309)     # (d, n): primal, samples rows
 NEWS20 = (62061, 15935)       # (d, n): dual, samples columns
@@ -50,15 +53,20 @@ def _sds(shape, sharding, dtype=F32):
 
 def _operand(layout, sharding):
     """The operand pytree the solvers bind, as shapes: row slabs of the
-    real-sim X, or the padded news20 X."""
+    real-sim X ("rows"), row slabs of the news20 X^T, (n, d_pad/128, 128)
+    ("cols"), or the padded news20 X the column gather reads ("gather")."""
     if layout == "rows":
         d, n = REAL_SIM
         c_pad = tuning.padded_contraction(n, F32, "rows")
         return RowMajorOperand.tree_unflatten(
             (d, n), [_sds((d, c_pad // 128, 128), sharding)])
     d, n = NEWS20
+    if layout == "cols":
+        c_pad = tuning.padded_contraction(d, F32, "rows")
+        return ColMajorOperand.tree_unflatten(
+            (n, d), [_sds((n, c_pad // 128, 128), sharding)])
     c_pad = tuning.padded_contraction(d, F32, "cols")
-    return ColMajorOperand.tree_unflatten(
+    return _ColGatherOperand.tree_unflatten(
         (n, d), [_sds((c_pad, -(-n // 128) * 128), sharding)])
 
 
@@ -68,7 +76,7 @@ def _assert_kernel(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("layout", ["rows", "cols"])
+@pytest.mark.parametrize("layout", ["rows", "cols", "gather"])
 @pytest.mark.parametrize("method", ["packet", "apply", "matvec"])
 def test_gather_kernel_compiles(one_chip, layout, method):
     op = _operand(layout, one_chip)
@@ -86,6 +94,17 @@ def test_gather_kernel_compiles(one_chip, layout, method):
         vec = _sds((op.contraction,), one_chip)
         fn = lambda op, f, t: op.matvec(f, t, scale=1.0, **kw)  # noqa: E731
     _assert_kernel(fn, op, flat, vec)
+
+
+def test_col_slab_build_fits(one_chip):
+    """The news20 X^T slab build compiles to one program whose temporaries
+    stay at one block's worth (<= 64 MB), next to raw X and the output."""
+    d, n = NEWS20
+    c_pad = tuning.padded_contraction(d, F32, "rows")
+    compiled = _col_slabs.lower(_sds(NEWS20, one_chip), c_pad).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == n * c_pad * 4
+    assert mem.temp_size_in_bytes <= 64 * 2**20, mem.temp_size_in_bytes
 
 
 def test_gram_packet_pallas_compiles(one_chip):

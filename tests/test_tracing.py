@@ -75,6 +75,25 @@ def test_unnamed_fusion_takes_its_fused_phase():
                                       "broadcast_select_fusion.2": "sweep"}
 
 
+def test_unnamed_fusion_phase_skips_merged_constant():
+    """A fused constant names whichever op XLA's constant merging kept
+    (here the outer loop's); the fusion's working instructions decide."""
+    text = "\n".join([
+        "%fused_computation.27 (p: f32[16,60]) -> f32[2,1,8,64] {",
+        '  %c = f32[] constant(0), metadata={op_name="jit(f)/engine.loop/'
+        'while/body/closed_call"}',
+        '  %pad.20 = f32[16,64]{1,0} pad(%p, %c), padding=0_0x0_4, '
+        'metadata={op_name="jit(f)/engine.loop/while/body/closed_call/'
+        'engine.metrics/jit(_pad)/pad"}',
+        "  ROOT %b = f32[2,1,8,64]{3,2,1,0} bitcast(%pad.20)",
+        "}",
+        "ENTRY %main (x: f32[16,60]) -> f32[2,1,8,64] {",
+        "  ROOT %pad_bitcast_fusion.2 = f32[2,1,8,64]{3,2,1,0} fusion(%x), "
+        "kind=kLoop, calls=%fused_computation.27",
+        "}"])
+    assert engine_phase_map(text)["pad_bitcast_fusion.2"] == "metrics"
+
+
 def _regression(d, n):
     X = jax.random.normal(jax.random.key(0), (d, n), jnp.float32)
     return X, X.T @ jnp.ones((d,), jnp.float32)
