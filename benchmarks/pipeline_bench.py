@@ -21,7 +21,8 @@ from repro.core.cost_model import (TPU_V5E_ICI, pipeline_schedule,
 
 from ._util import row
 
-# The dryrun production mesh (launch/mesh.py): 256 chips as (16, 16).
+# The dry-run production mesh (launch/solver_dryrun.make_production_mesh):
+# 256 chips as (16, 16).
 PROD_AXES = (16, 16)
 # The batched serving point of serve_bench / DESIGN.md section 8.
 PROD = dict(d=4096, n=1 << 22, b=8, s=8)

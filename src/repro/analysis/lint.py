@@ -7,9 +7,7 @@ reviewed, documented exception, not a hole):
 * raw-collective: ``jax.lax.psum``/``pmax``/... and ``shard_map`` may be
   CALLED only in ``core/engine.py`` (the solvers' single communication
   point, ``_packet_reduce``).  Anything else either routes through the
-  engine or carries an ``allow-collective`` waiver (e.g. the flash-decode
-  layer, whose fused softmax reduction is deliberately its own
-  communication point).
+  engine or carries an ``allow-collective`` waiver.
 * operand-transpose: inside classes that implement the Formulation/bound
   hooks (``bind``/``bind_shard``/``packet_vector``/``update``/
   ``inner_sweep``/``init_carry``/``metrics``), no ``.T`` -- the PR-5 rule

@@ -8,15 +8,17 @@ Guarantees (all tested in tests/test_checkpoint.py):
   * **Integrity** -- per-leaf CRC32 in the manifest; restore verifies and
     falls back to the next-older checkpoint if any leaf fails (bit-rot /
     truncated write after a node failure).
-  * **Exact resume** -- the data-iterator state (and any user extras) ride in
-    the manifest, so restart reproduces the exact batch stream.
+  * **Exact resume** -- user extras (the supervisor's completed iteration
+    count and current s) ride in the manifest, so a restart resumes at the
+    exact iteration it snapshotted.
   * **Elastic restarts** -- arrays are stored with *logical* (unsharded)
-    shapes; restore device_puts onto whatever mesh/sharding the new job uses
-    (train/elastic.py), so the same checkpoint restarts on a different device
-    count.
-  * **Async** -- saves run on a writer thread off the training critical path
-    (state is device_get'd synchronously -- cheap relative to a step -- and
-    serialized in the background).  keep=N pruning runs after each commit.
+    shapes, so the same checkpoint restarts on a different device count
+    (the supervisor re-plans its mesh with
+    ``faults.supervisor.plan_solver_mesh``).
+  * **Async** -- saves run on a writer thread off the solve's critical path
+    (state is device_get'd synchronously -- cheap relative to a segment --
+    and serialized in the background).  keep=N pruning runs after each
+    commit.
   * **No silent writer death** -- an exception on the background writer is
     captured and re-raised as :class:`CheckpointWriteError` on the next
     ``save()`` / ``wait()`` / ``close()``, so a failed async snapshot (disk

@@ -1,5 +1,3 @@
 from .regression import SyntheticSpec, make_regression, PAPER_DATASETS
-from .tokens import TokenStream, synthetic_lm_batch
 
-__all__ = ["SyntheticSpec", "make_regression", "PAPER_DATASETS",
-           "TokenStream", "synthetic_lm_batch"]
+__all__ = ["SyntheticSpec", "make_regression", "PAPER_DATASETS"]

@@ -1,6 +1,6 @@
 """Fault injection + supervised solves for the s-step engine (DESIGN.md
 section 7).  ``FaultPlan`` is import-light (tests thread it into every
-solver); the supervisor pulls in the checkpoint/elastic stack lazily."""
+solver); the supervisor pulls in the checkpoint stack lazily."""
 from .plan import KINDS, FaultPlan
 
 __all__ = ["FaultPlan", "KINDS", "DeviceLostError", "SupervisedResult",

@@ -10,7 +10,7 @@ for free), and a device loss -- simulated by a ``device_loss``
 :class:`~repro.faults.FaultPlan`, raised host-side as
 :class:`DeviceLostError` at the segment that contains the injected step --
 triggers a bounded-retry restart with exponential backoff: re-plan a 1D mesh
-over the survivors (``train.elastic.plan_solver_mesh``), restore the newest
+over the survivors (:func:`plan_solver_mesh`), restore the newest
 valid snapshot, and resume from its iteration.  Because
 ``Formulation.pad_shards`` re-pads the LOGICAL operands to any shard count
 and the sharded warm start re-derives the device-varying half of the carry
@@ -37,8 +37,16 @@ import time
 import jax
 
 from repro.checkpoint import CheckpointManager
+from repro.core.distributed import make_solver_mesh
 from repro.core.engine import _resolve_form, get_solver, sample_blocks
-from repro.train.elastic import plan_solver_mesh
+
+
+def plan_solver_mesh(n_devices: int, name: str = "shards"):
+    """A 1D mesh over the surviving world size, capped at the devices
+    actually present.  ``Formulation.pad_shards`` re-pads the logical
+    operands to any shard count, so an elastic restart after device loss is
+    just this mesh plus a warm start from the newest checkpoint."""
+    return make_solver_mesh(max(1, min(n_devices, len(jax.devices()))), name)
 
 
 class DeviceLostError(RuntimeError):

@@ -1,2 +1,1 @@
-"""Launchers: production mesh construction, the multi-pod dry-run, and the
-real train/serve drivers."""
+"""Launchers: the s-step solver's dry-run at the production mesh."""

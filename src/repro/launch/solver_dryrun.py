@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# (must precede all other imports -- see dryrun.py)
+# (must precede all other imports: the CPU backend reads the device count
+# once, when JAX first initializes)
 
 """The paper's own technique at the production mesh: lower + compile
 (CA-)BCD/(CA-)BDCD on 256 chips (16x16, flattened 1D layout over both axes)
@@ -22,10 +23,20 @@ import argparse
 import json
 import time
 
+import jax
+
 from repro.core import FORMULATIONS, count_in_compiled
 from repro.core.cost_model import TPU_V5E_ICI, pipeline_schedule
 from repro.core.distributed import lower_solver, lower_solver_batched
-from repro.launch.mesh import make_production_mesh
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    """16x16 = 256 chips per pod ('data', 'model'); multi_pod prepends a
+    2-pod DCN axis ('pod', 'data', 'model') = 512 chips."""
+    shape = (2, 16, 16) if multi_pod else (16, 16)
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def _overlap_fields(mesh, b: int, s: int, tenants: int = 1,

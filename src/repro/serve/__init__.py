@@ -1,3 +1,1 @@
-from .engine import Engine, ServeConfig
-
-__all__ = ["Engine", "ServeConfig"]
+"""The s-step solve service (`solver_service`) and its slot table (`slots`)."""

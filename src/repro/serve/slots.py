@@ -1,18 +1,12 @@
-"""Slot-table continuous batching: the bookkeeping shared by every serving
-front end in this repo.
+"""Slot-table continuous batching: the bookkeeping of the solve service.
 
-Two engines batch very different payloads over the same skeleton:
-
-  * :class:`~repro.serve.engine.Engine` decodes tokens -- a slot owns a KV /
-    state-cache stripe,
-  * :class:`~repro.serve.solver_service.SolverService` advances s-step
-    solves -- a slot owns a tenant's (w, alpha) carry row,
-
-and both need exactly this machinery: a FIFO admission queue, a fixed-width
+:class:`~repro.serve.solver_service.SolverService` advances s-step solves,
+and a slot owns one tenant's (w, alpha) carry row.  This module holds the
+part that knows nothing of solves: a FIFO admission queue, a fixed-width
 table of slots each bound to at most one live request, and power-of-two
 bucketing so the number of distinct compiled shapes stays logarithmic in the
-width being padded.  The domain state (caches, carries, positions) stays in
-the engine; the table only tracks which request sits where.
+width being padded.  The domain state (the carries) stays in the service;
+the table only tracks which request sits where.
 """
 from __future__ import annotations
 
@@ -36,9 +30,9 @@ def bucket_pow2(n: int, min_bucket: int, cap: int) -> int:
 
 @dataclasses.dataclass
 class SlotRequest:
-    """One queued or running request.  ``payload`` is the engine's input
-    (prompt tokens, solver right-hand side...), ``out`` accumulates the
-    engine's output, ``slot`` is -1 until admitted."""
+    """One queued or running request.  ``payload`` is the service's input
+    (the solver's right-hand side and coefficients), ``out`` accumulates the
+    service's output, ``slot`` is -1 until admitted."""
     rid: int
     payload: object
     out: list
@@ -49,11 +43,11 @@ class SlotRequest:
 class SlotTable:
     """Fixed-width slot table + FIFO queue.
 
-    The lifecycle every engine shares: ``submit`` enqueues, ``admit`` moves
-    queued requests into free slots (the engine installs its domain state
-    per admission), ``retire`` frees a slot and marks the request done.
-    ``active`` is a numpy bool mask over slots -- engines ship it (or a
-    gathered view) to the device as their no-op mask.
+    The lifecycle: ``submit`` enqueues, ``admit`` moves queued requests
+    into free slots (the service installs its domain state per admission),
+    ``retire`` frees a slot and marks the request done.  ``active`` is a
+    numpy bool mask over slots -- the service ships it (or a gathered view)
+    to the device as its no-op mask.
     """
 
     def __init__(self, slots: int):
@@ -78,8 +72,7 @@ class SlotTable:
     def admit(self) -> list[SlotRequest]:
         """Move queued requests into free slots (FIFO x first-free), mark
         them active, and return the newly admitted requests so the caller
-        can install its per-slot domain state (prefill a cache stripe, seed
-        a solver carry row...)."""
+        can install its per-slot domain state (seed a solver carry row)."""
         admitted = []
         for s in range(self.slots):
             if self.active[s] or not self.queue:

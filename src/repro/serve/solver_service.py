@@ -1,21 +1,20 @@
 """Continuous-batching front end for the tenant-batched s-step engine.
 
-The serving analogue of :class:`repro.serve.engine.Engine`, built on the
-same :class:`~repro.serve.slots.SlotTable`: solve requests (a target ``y``,
-an l2 weight ``lam``, optional formulation coefficients, a per-request
-residual tolerance) queue into free slots, and every :meth:`step` advances
-ALL live solves by one chunk of iterations through ONE
-:func:`~repro.core.s_step_solve_batched` call -- one scan, one Gram packet
-per outer step, shared by every tenant in the chunk.
+Built on :class:`~repro.serve.slots.SlotTable`: solve requests (a target
+``y``, an l2 weight ``lam``, optional formulation coefficients, a
+per-request residual tolerance) queue into free slots, and every
+:meth:`step` advances ALL live solves by one chunk of iterations through
+ONE :func:`~repro.core.s_step_solve_batched` call -- one scan, one Gram
+packet per outer step, shared by every tenant in the chunk.
 
-Compile discipline mirrors the token engine's prompt buckets: the live
-tenants are gathered into a power-of-two bucket (padded rows ride inactive,
-masked to no-ops), and each ``(bucket, formulation)`` pair traces and
-compiles exactly once -- a service processing thousands of requests touches
-O(log slots) lowered shapes total.  The shared operand is built once, in
-the formulation's kernel layout, and handed to every compiled chunk as an
-argument: a closed-over array would be baked into the program as a
-constant, and a raw one would be re-laid-out on every chunk.
+Compile discipline: the live tenants are gathered into a power-of-two
+bucket (padded rows ride inactive, masked to no-ops), and each
+``(bucket, formulation)`` pair traces and compiles exactly once -- a service
+processing thousands of requests touches O(log slots) lowered shapes total.
+The shared operand is built once, in the formulation's kernel layout, and
+handed to every compiled chunk as an argument: a closed-over array would be
+baked into the program as a constant, and a raw one would be re-laid-out on
+every chunk.
 
 Retirement is two-level, matching DESIGN.md section 8:
 

@@ -293,59 +293,6 @@ def check_batched_collectives():
     print("batched_collectives OK")
 
 
-def check_flash_decode():
-    """Sequence-sharded flash-decoding == dense decode attention."""
-    from repro.models.layers import decode_attention, decode_attention_seqsharded
-    mesh = jax.make_mesh((8,), ("model",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-    B, S, H, Hkv, Dh = 2, 64, 8, 4, 16
-    ks = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(ks[0], (B, 1, H, Dh), jnp.float32)
-    ck = jax.random.normal(ks[1], (B, S, Hkv, Dh), jnp.float32)
-    cv = jax.random.normal(ks[2], (B, S, Hkv, Dh), jnp.float32)
-    pos = jnp.asarray([37, 11], jnp.int32)
-    # note: dense path broadcasts per-request positions
-    dense = decode_attention(q, ck, cv, pos)
-    flash = decode_attention_seqsharded(q, ck, cv, pos, mesh=mesh,
-                                        axis="model")
-    np.testing.assert_allclose(flash, dense, rtol=1e-5, atol=1e-5)
-
-    # and it psums a tiny packet instead of gathering the cache
-    from repro.core import collective_summary
-    comp = jax.jit(lambda a, b, c: decode_attention_seqsharded(
-        a, b, c, pos, mesh=mesh, axis="model")).lower(q, ck, cv).compile()
-    s = collective_summary(comp.as_text())
-    cache_bytes = 2 * B * S * Hkv * Dh * 4
-    assert s.operand_bytes < cache_bytes / 4, s
-    print("flash_decode OK")
-
-
-def check_elastic_reshard():
-    """Train on 8 devices, checkpoint, restore on a 4-device mesh, continue."""
-    import tempfile
-    from repro.configs import get_reduced
-    from repro.train import Trainer, TrainRunConfig
-    from repro.train.elastic import plan_mesh
-    cfg = get_reduced("granite_3_2b")
-    with tempfile.TemporaryDirectory() as d:
-        rc = TrainRunConfig(steps=2, global_batch=8, seq_len=32, ckpt_dir=d,
-                            save_every=2, log_every=1)
-        mesh8 = plan_mesh(8, tp=2)
-        t1 = Trainer(cfg, rc, mesh=mesh8)
-        t1.run()
-        # restart on 4 devices (simulated shrink)
-        mesh4 = jax.sharding.Mesh(
-            np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"),
-            axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        rc2 = TrainRunConfig(steps=4, global_batch=8, seq_len=32, ckpt_dir=d,
-                             save_every=2, log_every=1)
-        t2 = Trainer(cfg, rc2, mesh=mesh4)
-        assert int(t2.state["step"]) == 2
-        t2.run()
-        assert int(t2.state["step"]) == 4
-    print("elastic_reshard OK")
-
-
 def check_engine_phases():
     """The engine's named phases in the sharded programs: every working
     instruction of every loop body of the psum and ring drivers (single and
@@ -410,8 +357,7 @@ def check_engine_phases():
 CHECKS = {f.__name__.replace("check_", ""): f for f in
           (check_solver_equivalence, check_pipelined_wire,
            check_collective_counts, check_collective_counts_pallas,
-           check_batched_collectives, check_flash_decode,
-           check_elastic_reshard, check_engine_phases)}
+           check_batched_collectives, check_engine_phases)}
 
 if __name__ == "__main__":
     CHECKS[sys.argv[1]]()

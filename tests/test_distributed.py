@@ -5,8 +5,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 _SCRIPT = os.path.join(os.path.dirname(__file__), "dist_checks.py")
 _SRC = os.path.join(os.path.dirname(__file__), os.pardir, "src")
 
@@ -49,12 +47,3 @@ def test_batched_collectives_independent_of_tenants():
     T in {1, 8, 64}, per-step payload sb^2 + T*sb words (shared Gram not
     scaled by T)."""
     _run("batched_collectives")
-
-
-def test_flash_decode_seqsharded():
-    _run("flash_decode")
-
-
-@pytest.mark.slow
-def test_elastic_reshard():
-    _run("elastic_reshard")

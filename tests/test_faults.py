@@ -166,6 +166,24 @@ def test_supervised_restart_budget_exhausted(tmp_path):
                          fault=FaultPlan("device_loss", step=0))
 
 
+def test_plan_solver_mesh_caps_at_devices_present():
+    """A restart asks for the survivors' count; the mesh never holds more
+    devices than the process has, and never fewer than one."""
+    from repro.faults.supervisor import plan_solver_mesh
+    present = len(jax.devices())
+    for asked in (0, 1, present, present + 7):
+        mesh = plan_solver_mesh(asked)
+        assert mesh.devices.shape == (max(1, min(asked, present)),), asked
+        assert mesh.axis_names == ("shards",)
+
+
+def test_plan_solver_mesh_names_the_given_axis():
+    from repro.faults.supervisor import plan_solver_mesh
+    mesh = plan_solver_mesh(1, "rows")
+    assert mesh.axis_names == ("rows",)
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+
+
 def test_snapshot_cadence_model():
     from repro.core.cost_model import TPU_V5E_ICI, snapshot_cadence
     out = snapshot_cadence(TPU_V5E_ICI, d=1 << 16, n=1 << 20, P=64, b=8,
